@@ -14,8 +14,13 @@ RESULTS = os.path.join(REPO, "results", "bench")
 def run_halo_child(backend: str, devices: int = 8, box: int = 16,
                    steps: int = 2, runs: int = 5, emit_trace: bool = False,
                    emit_hlo_stats: bool = False) -> dict:
+    """Run the halo app in a child process on ``devices`` host CPU devices.
+
+    This harness is CPU-only: the child's environment pins JAX to the CPU
+    and forces the device count, so it never contends with the parent (or
+    anything else) for an attached chip."""
     cmd = [sys.executable, "-m", "benchmarks.halo_child",
-           "--backend", backend, "--devices", str(devices),
+           "--backend", backend,
            "--box", str(box), "--steps", str(steps), "--runs", str(runs)]
     if emit_trace:
         cmd.append("--emit-trace")
@@ -23,6 +28,8 @@ def run_halo_child(backend: str, devices: int = 8, box: int = 16,
         cmd.append("--emit-hlo-stats")
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src") + ":" + REPO
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     out = subprocess.run(cmd, capture_output=True, text=True, env=env,
                          cwd=REPO, timeout=900)
     if out.returncode != 0:

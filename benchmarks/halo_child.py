@@ -1,28 +1,42 @@
 """Child process: run the COMB-analog halo app under one comm backend on
-N host devices and emit per-run GraphFrames + wall times + a trace as JSON.
+the devices JAX sees and emit per-run GraphFrames + wall times + a trace
+as JSON.
 
-Invoked by the benchmark harness:
-    python -m benchmarks.halo_child --backend explicit_overlap --devices 8 \
+Invoked by the benchmark harness (``benchmarks.common.run_halo_child``),
+which sets the platform and device count in the environment:
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        python -m benchmarks.halo_child --backend explicit_overlap \
         --box 32 --steps 4 --runs 5
 """
 import argparse
 import json
-import os
+
+
+def mesh_dims(n: int) -> tuple:
+    """Split n devices over (x, y, z) as evenly as its prime factors allow:
+    8 -> (2, 2, 2), 4 -> (2, 2, 1), 6 -> (3, 2, 1)."""
+    dims = [1, 1, 1]
+    rest, f = n, 2
+    factors = []
+    while rest > 1:
+        while rest % f:
+            f += 1
+        factors.append(f)
+        rest //= f
+    for f in sorted(factors, reverse=True):
+        dims[dims.index(min(dims))] *= f
+    return tuple(sorted(dims, reverse=True))
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--backend", required=True)
-    ap.add_argument("--devices", type=int, default=8)
     ap.add_argument("--box", type=int, default=32, help="local box edge")
     ap.add_argument("--steps", type=int, default=4)
     ap.add_argument("--runs", type=int, default=5)
     ap.add_argument("--emit-trace", action="store_true")
     ap.add_argument("--emit-hlo-stats", action="store_true")
     args = ap.parse_args()
-
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={args.devices}")
 
     import time
 
@@ -35,12 +49,14 @@ def main():
     from repro.comm.halo import HaloProgram, make_halo_fn, make_xla_auto_fn
     from repro.core import regions, timeline
     from repro.core.collector import reset_global_collector
+    from repro.core.compat import make_mesh
+    from repro.core.compile_cache import enable_compile_cache
     from repro.core.graphframe import GraphFrame
 
+    enable_compile_cache()
     backend = get_backend(args.backend)
-    n = args.devices
-    dims = {8: (2, 2, 2), 4: (2, 2, 1), 2: (2, 1, 1), 1: (1, 1, 1)}[n]
-    from repro.core.compat import make_mesh
+    n = len(jax.devices())
+    dims = mesh_dims(n)
     mesh = make_mesh(dims, ("x", "y", "z"))
     edge = args.box
     global_shape = (dims[0] * edge, dims[1] * edge, dims[2] * edge)
@@ -80,7 +96,8 @@ def main():
 
     out = run_once(u0)                  # warmup/compile
     jax.block_until_ready(out)
-    checksum = float(jnp.sum(jnp.abs(out.astype(jnp.float64))))
+    # on the host: without x64, astype(float64) on a device stays float32
+    checksum = float(np.abs(np.asarray(out, np.float64)).sum())
 
     frames, walls, trace = [], [], None
     for r in range(args.runs):

@@ -45,7 +45,7 @@ def comm_layer_tour():
     from repro.comm import collectives
     from repro.comm.ring import ring_all_gather
     from repro.core import timeline
-    from repro.core.compat import make_mesh, shard_map
+    from repro.core.compat import make_mesh
     from repro.core.counters import CounterRegistry
     from repro.core.graphframe import GraphFrame
     from repro.match import Fabric
@@ -61,12 +61,12 @@ def comm_layer_tour():
     try:
         mesh = make_mesh((n,), ("r",))
         x = jnp.arange(n * 4 * 2, dtype=jnp.float32).reshape(n * 4, 2)
-        out = jax.jit(shard_map(
+        out = jax.jit(jax.shard_map(
             lambda s: ring_all_gather(s, "r"),
             mesh=mesh, in_specs=P("r", None), out_specs=P("r", None)))(x)
         jax.block_until_ready(out)
         y = jnp.ones((n, 4), jnp.float32)
-        out2 = jax.jit(shard_map(
+        out2 = jax.jit(jax.shard_map(
             lambda s: collectives.psum(s, "r"),
             mesh=mesh, in_specs=P("r", None), out_specs=P(None, None)))(y)
         jax.block_until_ready(out2)

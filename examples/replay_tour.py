@@ -33,7 +33,7 @@ def record_live_run():
 
     from repro.comm import collectives
     from repro.comm.ring import ring_all_gather
-    from repro.core.compat import make_mesh, shard_map
+    from repro.core.compat import make_mesh
     from repro.core.counters import CounterRegistry
     from repro.trace import record_collectives
 
@@ -44,12 +44,12 @@ def record_live_run():
                             meta={"example": "replay_tour"}) as fab:
         mesh = make_mesh((n,), ("r",))
         x = jnp.arange(n * 4 * 2, dtype=jnp.float32).reshape(n * 4, 2)
-        out = jax.jit(shard_map(
+        out = jax.jit(jax.shard_map(
             lambda s: ring_all_gather(s, "r"),
             mesh=mesh, in_specs=P("r", None), out_specs=P("r", None)))(x)
         jax.block_until_ready(out)
         y = jnp.ones((n, 4), jnp.float32)
-        out2 = jax.jit(shard_map(
+        out2 = jax.jit(jax.shard_map(
             lambda s: collectives.psum(s, "r"),
             mesh=mesh, in_specs=P("r", None), out_specs=P(None, None)))(y)
         jax.block_until_ready(out2)

@@ -82,8 +82,7 @@ def main():
         y = jnp.einsum("bd,df->bf", x, w)
         return jax.lax.psum(y, "model")
 
-    from repro.core.compat import shard_map
-    f = shard_map(tp_layer, mesh=mesh,
+    f = jax.shard_map(tp_layer, mesh=mesh,
                   in_specs=(P(None, None), P(None, "model")),
                   out_specs=P(None, None))
     txt = jax.jit(f).lower(

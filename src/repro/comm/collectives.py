@@ -26,7 +26,7 @@ from typing import Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 
-from ..core import compat, regions
+from ..core import regions
 
 AxisName = Union[str, Tuple[str, ...]]
 
@@ -69,7 +69,7 @@ def psum(x: jax.Array, axis_name: AxisName) -> jax.Array:
                           bytes=_nbytes(x)):
         if _FABRIC is not None:
             with comm_phase(f"psum({axis_name})"):
-                _FABRIC.all_reduce(compat.axis_size(axis_name),
+                _FABRIC.all_reduce(jax.lax.axis_size(axis_name),
                                    nbytes=_nbytes(x))
         with jax.named_scope(f"comm_psum_{axis_name}"):
             return jax.lax.psum(x, axis_name)
@@ -81,7 +81,7 @@ def all_gather(x: jax.Array, axis_name: AxisName, axis: int = 0,
                           bytes=_nbytes(x)):
         if _FABRIC is not None:
             with comm_phase(f"all_gather({axis_name})"):
-                _FABRIC.all_gather(compat.axis_size(axis_name),
+                _FABRIC.all_gather(jax.lax.axis_size(axis_name),
                                    nbytes=_nbytes(x))
         with jax.named_scope(f"comm_all_gather_{axis_name}"):
             return jax.lax.all_gather(x, axis_name, axis=axis, tiled=tiled)
@@ -93,7 +93,7 @@ def reduce_scatter(x: jax.Array, axis_name: AxisName,
                           category="collective", bytes=_nbytes(x)):
         if _FABRIC is not None:
             with comm_phase(f"reduce_scatter({axis_name})"):
-                _FABRIC.reduce_scatter(compat.axis_size(axis_name),
+                _FABRIC.reduce_scatter(jax.lax.axis_size(axis_name),
                                        nbytes=_nbytes(x))
         with jax.named_scope(f"comm_reduce_scatter_{axis_name}"):
             return jax.lax.psum_scatter(
@@ -106,7 +106,7 @@ def all_to_all(x: jax.Array, axis_name: AxisName, split_axis: int,
                           bytes=_nbytes(x)):
         if _FABRIC is not None:
             with comm_phase(f"all_to_all({axis_name})"):
-                _FABRIC.all_to_all(compat.axis_size(axis_name),
+                _FABRIC.all_to_all(jax.lax.axis_size(axis_name),
                                    nbytes=_nbytes(x))
         with jax.named_scope(f"comm_all_to_all_{axis_name}"):
             return jax.lax.all_to_all(
@@ -132,4 +132,4 @@ def axis_index(axis_name: AxisName) -> jax.Array:
 
 
 def axis_size(axis_name: AxisName) -> int:
-    return compat.axis_size(axis_name)
+    return jax.lax.axis_size(axis_name)

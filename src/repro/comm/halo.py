@@ -23,15 +23,14 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..core import compat, regions
-from ..core.compat import shard_map
+from ..core import regions
 from . import patterns
 from .collectives import comm_phase, ppermute
 
 
 def _shift(x: jax.Array, axis_name: str, direction: int,
            ax: int = 0) -> jax.Array:
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     # perm + envelope tag per (mesh axis position, direction) come from
     # comm.patterns so the matching engine and the offline workload
     # scenarios see the exact message streams the stencil issues
@@ -132,7 +131,7 @@ def make_halo_fn(mesh: Mesh, width: int = 1, variant: str = "overlap",
             u = halo_step(u, axis_names=axes, width=width, variant=variant)
         return u
 
-    return shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec)
+    return jax.shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec)
 
 
 class HaloProgram:
@@ -193,7 +192,7 @@ class HaloProgram:
 
         fspec = {n: (spec, spec) for n in axes}
         if explicit:
-            sm = functools.partial(shard_map, mesh=mesh)
+            sm = functools.partial(jax.shard_map, mesh=mesh)
             self.extract = jax.jit(sm(extract, in_specs=spec,
                                       out_specs=fspec))
             self.exchange = jax.jit(sm(exchange, in_specs=(fspec,),

@@ -23,7 +23,7 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..core import compat, regions
+from ..core import regions
 from . import patterns
 from .collectives import comm_phase, ppermute
 
@@ -37,7 +37,7 @@ def ring_all_gather(
 ) -> jax.Array:
     """All-gather x (local shard) along axis_name via a ppermute ring.
     Returns (n * x.shape[0], ...) with shard i at block i."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = _ring_perm(n)
     out = jnp.zeros((n,) + x.shape, x.dtype)
@@ -63,7 +63,7 @@ def ring_all_reduce(
     x: jax.Array, axis_name: str, schedule: str = "overlap"
 ) -> jax.Array:
     """reduce-scatter + all-gather ring all-reduce by chunks."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     idx = jax.lax.axis_index(axis_name)
@@ -115,7 +115,7 @@ def overlap_matmul_allgather(
     step k multiplies the chunk that just arrived while the next chunk is
     on the wire. The serial schedule gathers everything first (fully
     exposed wire time); the overlap schedule is the paper's fix."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = _ring_perm(n)
     rows = x_shard.shape[0]
@@ -149,7 +149,7 @@ def reduce_scatter_matmul(
 ) -> jax.Array:
     """y = reduce_scatter(x @ w, rows) — row-chunked so each chunk's ring
     reduction rides the wire while the next chunk is on the MXU."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     partial = x @ w_shard
     if n == 1:
         return partial

@@ -1,10 +1,8 @@
-"""JAX version-compatibility shims.
+"""Mesh construction in one place.
 
-The repo targets the modern JAX API surface (``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``, ``jax.sharding.AxisType``) but must
-also run on older installs (0.4.x) where shard_map lives in
-``jax.experimental`` and meshes have no axis_types concept. Every call
-site goes through these helpers instead of feature-detecting locally.
+Meshes are built with ``AxisType.Auto`` axes, so GSPMD propagates
+shardings through un-annotated ops the way every caller in this repo
+expects. Every mesh in the repo is built through these helpers.
 """
 from __future__ import annotations
 
@@ -12,69 +10,23 @@ from typing import Optional, Sequence
 
 import jax
 
-__all__ = ["axis_size", "axis_types_kwargs", "make_mesh", "mesh_from_devices",
-           "shard_map"]
+__all__ = ["make_mesh", "mesh_from_devices"]
 
 
-def axis_size(axis_name) -> int:
-    """Static size of a mapped axis. Old JAX has no ``jax.lax.axis_size``;
-    ``psum(1, axis)`` is constant-folded to the same value at trace time."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return int(jax.lax.psum(1, axis_name))
-
-
-def _axis_type_auto():
-    return getattr(jax.sharding, "AxisType", None) and jax.sharding.AxisType.Auto
-
-
-def axis_types_kwargs(n_axes: int) -> dict:
-    """``{"axis_types": (Auto,) * n}`` on JAX versions that support it,
-    ``{}`` otherwise (old meshes behave as Auto implicitly)."""
-    auto = _axis_type_auto()
-    return {"axis_types": (auto,) * n_axes} if auto else {}
+def _auto_axes(n_axes: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               devices: Optional[Sequence] = None) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where supported, falling back
-    to a reshaped-devices ``Mesh`` on versions predating ``jax.make_mesh``."""
-    shape, names = tuple(axis_shapes), tuple(axis_names)
-    if not hasattr(jax, "make_mesh"):
-        import math
-        import numpy as np
-        devs = list(devices) if devices is not None else jax.devices()
-        return mesh_from_devices(
-            np.asarray(devs[: math.prod(shape)]).reshape(shape), names)
-    kwargs = axis_types_kwargs(len(shape))
-    if devices is not None:
-        kwargs["devices"] = devices
-    try:
-        return jax.make_mesh(shape, names, **kwargs)
-    except TypeError:
-        kwargs.pop("axis_types", None)
-        return jax.make_mesh(shape, names, **kwargs)
+    """``jax.make_mesh`` with Auto axis types."""
+    shape = tuple(axis_shapes)
+    return jax.make_mesh(shape, tuple(axis_names),
+                         axis_types=_auto_axes(len(shape)), devices=devices)
 
 
 def mesh_from_devices(device_array, axis_names: Sequence[str]) -> jax.sharding.Mesh:
     """``jax.sharding.Mesh`` from an explicit device ndarray, with Auto
-    axis types where supported (the elastic-restart construction path)."""
-    kwargs = axis_types_kwargs(device_array.ndim)
-    try:
-        return jax.sharding.Mesh(device_array, tuple(axis_names), **kwargs)
-    except TypeError:
-        return jax.sharding.Mesh(device_array, tuple(axis_names))
-
-
-def _resolve_shard_map():
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    from jax.experimental.shard_map import shard_map as sm  # noqa: F811
-    return sm
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, **kwargs):
-    """Version-portable ``shard_map`` (keyword-only, like modern JAX)."""
-    return _resolve_shard_map()(f, mesh=mesh, in_specs=in_specs,
-                                out_specs=out_specs, **kwargs)
+    axis types (the elastic-restart construction path)."""
+    return jax.sharding.Mesh(device_array, tuple(axis_names),
+                             axis_types=_auto_axes(device_array.ndim))

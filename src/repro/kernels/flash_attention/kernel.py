@@ -70,12 +70,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (bq, bk)
         s = jnp.where(_mask(i, j, bq, bk, causal, window), s, NEG_INF)
-        m_prev = m_sc[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_sc[...]                                # (bq, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_sc[...] = l_sc[...] * corr + p.sum(axis=-1)
-        acc_sc[...] = acc_sc[...] * corr[:, None] + jax.lax.dot_general(
+        l_sc[...] = l_sc[...] * corr + p.sum(axis=-1, keepdims=True)
+        acc_sc[...] = acc_sc[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_sc[...] = m_new
@@ -83,7 +83,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc,
     @pl.when(j == nk - 1)
     def _finish():
         l = jnp.maximum(l_sc[...], 1e-30)
-        o_ref[0, 0] = (acc_sc[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_sc[...] / l).astype(o_ref.dtype)
         lse_ref[0, 0] = m_sc[...] + jnp.log(l)
 
 
@@ -94,7 +94,11 @@ def flash_attention_fwd(
     block_q: int = 128, block_k: int = 512,
     interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
-    """q,k,v: (B, H, T, D) -> (out (B,H,T,D), lse (B,H,T))."""
+    """q,k,v: (B, H, T, D) -> (out (B,H,T,D), lse (B,H,T,1)).
+
+    Row statistics (lse, and delta in the backward) keep a trailing unit
+    dim: Mosaic tiles the last two block dims by (8, 128) unless a dim is
+    whole, so a (bq, 1) column block lowers where a (1, bq) row does not."""
     B, H, T, D = q.shape
     S = k.shape[2]
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
@@ -113,16 +117,16 @@ def flash_attention_fwd(
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, T), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, T, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, D), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)
@@ -153,10 +157,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         s = jnp.where(_mask(i, j, bq, bk, causal, window), s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])
+        p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
+        ds = p * (dp - delta) * scale
         dq_sc[...] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -188,13 +192,13 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         s = jnp.where(_mask(i, j, bq, bk, causal, window), s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])                     # (bq, bk)
+        p = jnp.exp(s - lse)                              # (bq, bk)
         dv_sc[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)           # (bk, D)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale            # (bq, bk)
+        ds = p * (dp - delta) * scale                     # (bq, bk)
         dk_sc[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)           # (bk, D)
@@ -218,7 +222,8 @@ def flash_attention_bwd(
     bq = min(block_q, T)
     bk = min(block_k, S)
     nq, nk = T // bq, S // bk
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                    keepdims=True)                        # (B, H, T, 1)
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, causal=causal, window=window,
@@ -229,8 +234,8 @@ def flash_attention_bwd(
             pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h, j, 0)),
             pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h, j, 0)),
             pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
@@ -247,8 +252,8 @@ def flash_attention_bwd(
             pl.BlockSpec((1, 1, bk, D), lambda b, h, j, i: (b, h, j, 0)),
             pl.BlockSpec((1, 1, bk, D), lambda b, h, j, i: (b, h, j, 0)),
             pl.BlockSpec((1, 1, bq, D), lambda b, h, j, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, j, i: (b, h, i)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, j, i: (b, h, i)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, j, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, j, i: (b, h, i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, D), lambda b, h, j, i: (b, h, j, 0)),

@@ -2,9 +2,15 @@
 
 The jnp path materializes dA/dBx = (B, T, dI, N) intermediates chunk by
 chunk in HBM; this kernel never leaves VMEM with them. Grid is
-(B, dI/bd, T/bt) with time minor-most: the (bd, N) state scratch carries
-across time blocks, and each block runs a fori_loop over its bt steps
-with (bd, N) vector ops on the VPU.
+(B, dI/bd, T/bt) with time minor-most: the state scratch carries across
+time blocks, and each block steps through its bt time steps with
+(N, bd) vector ops on the VPU.
+
+The state is held as (N, bd), channels on lanes: at N=16 a (bd, N)
+layout would fill one lane in eight. So A, Bc and Cc enter transposed,
+(N, dI) and (B, N, T), and each time step reads a row of x/dt and a
+column of Bc/Cc at a static offset; Mosaic refuses the dynamic
+sub-tile row loads a fori_loop over time would need.
 
 HBM traffic per step: x, dt (bd*bt), Bc, Cc (bt*N), y (bd*bt) — i.e. the
 theoretical minimum (inputs+outputs once), vs the jnp path's
@@ -13,7 +19,6 @@ O(T * dI * N) intermediate traffic.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -22,28 +27,25 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _scan_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, h_sc,
-                 *, bt: int):
-    t_blk = pl.program_id(2)
-
-    @pl.when(t_blk == 0)
+                 y_sc, *, bt: int):
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         h_sc[...] = jnp.zeros_like(h_sc)
 
-    a = a_ref[...].astype(jnp.float32)                 # (bd, N)
-    d = d_ref[...].astype(jnp.float32)                 # (bd,)
-
-    def body(t, h):
-        xt = x_ref[0, t].astype(jnp.float32)           # (bd,)
-        dtt = dt_ref[0, t].astype(jnp.float32)         # (bd,)
-        bt_ = b_ref[0, t].astype(jnp.float32)          # (N,)
-        ct = c_ref[0, t].astype(jnp.float32)           # (N,)
-        dA = jnp.exp(dtt[:, None] * a)                 # (bd, N)
-        h = dA * h + (dtt * xt)[:, None] * bt_[None, :]
-        y = (h * ct[None, :]).sum(axis=1) + d * xt     # (bd,)
-        y_ref[0, t] = y.astype(y_ref.dtype)
-        return h
-
-    h_sc[...] = jax.lax.fori_loop(0, bt, body, h_sc[...])
+    a = a_ref[...].astype(jnp.float32)                 # (N, bd)
+    d = d_ref[...].astype(jnp.float32)                 # (1, bd)
+    x = x_ref[0].astype(jnp.float32)                   # (bt, bd)
+    dt = dt_ref[0].astype(jnp.float32)                 # (bt, bd)
+    b = b_ref[0].astype(jnp.float32)                   # (N, bt)
+    c = c_ref[0].astype(jnp.float32)                   # (N, bt)
+    h = h_sc[...]                                      # (N, bd)
+    for t in range(bt):
+        xt, dtt = x[t:t + 1], dt[t:t + 1]              # (1, bd)
+        h = jnp.exp(dtt * a) * h + (dtt * xt) * b[:, t:t + 1]
+        y_sc[t:t + 1, :] = ((h * c[:, t:t + 1]).sum(axis=0, keepdims=True)
+                            + d * xt)
+    h_sc[...] = h
+    y_ref[0] = y_sc[...].astype(y_ref.dtype)
 
 
 def selective_scan(
@@ -69,13 +71,15 @@ def selective_scan(
         in_specs=[
             pl.BlockSpec((1, bt, bd), lambda b, di, t: (b, t, di)),
             pl.BlockSpec((1, bt, bd), lambda b, di, t: (b, t, di)),
-            pl.BlockSpec((bd, N), lambda b, di, t: (di, 0)),
-            pl.BlockSpec((1, bt, N), lambda b, di, t: (b, t, 0)),
-            pl.BlockSpec((1, bt, N), lambda b, di, t: (b, t, 0)),
-            pl.BlockSpec((bd,), lambda b, di, t: (di,)),
+            pl.BlockSpec((N, bd), lambda b, di, t: (0, di)),
+            pl.BlockSpec((1, N, bt), lambda b, di, t: (b, 0, t)),
+            pl.BlockSpec((1, N, bt), lambda b, di, t: (b, 0, t)),
+            pl.BlockSpec((1, bd), lambda b, di, t: (0, di)),
         ],
         out_specs=pl.BlockSpec((1, bt, bd), lambda b, di, t: (b, t, di)),
         out_shape=jax.ShapeDtypeStruct((B, T, dI), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bd, N), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((N, bd), jnp.float32),
+                        pltpu.VMEM((bt, bd), jnp.float32)],
         interpret=interpret,
-    )(x, dt, A, Bc, Cc, D)
+    )(x, dt, A.T, Bc.transpose(0, 2, 1), Cc.transpose(0, 2, 1),
+      D.reshape(1, dI))

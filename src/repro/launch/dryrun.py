@@ -1,7 +1,10 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax-importing import: jax locks the device count at
-# first init. The dry-run (and only the dry-run) needs 512 placeholders.
+# ^ MUST precede any jax-importing import: jax locks the platform and the
+# device count at first init. The dry-run (and only the dry-run) needs 512
+# host placeholders; pinning it to the CPU keeps it, and the --all child
+# processes that inherit this environment, off any attached chip.
 
 import argparse          # noqa: E402
 import json              # noqa: E402

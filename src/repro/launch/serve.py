@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from ..configs.archs import get_config
 from ..core import regions
 from ..core.collector import global_collector, reset_global_collector
+from ..core.compile_cache import enable_compile_cache
 from ..core.graphframe import GraphFrame
 from ..models import model as M
 from ..train.step import make_decode_step, make_prefill_step
@@ -36,6 +37,7 @@ def main(argv=None):
     ap.add_argument("--telemetry-port", type=int, default=0,
                     help="bind port for --telemetry (default: ephemeral)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, args.preset)
     if cfg.input_mode != "tokens":

@@ -25,6 +25,7 @@ from ..checkpoint.straggler import StragglerDetector
 from ..configs.archs import get_config
 from ..core import regions, timeline
 from ..core.collector import global_collector, reset_global_collector
+from ..core.compile_cache import enable_compile_cache
 from ..core.graphframe import GraphFrame
 from ..data.pipeline import DataConfig, SyntheticTokens
 from ..models import model as M
@@ -53,6 +54,7 @@ def main(argv=None):
                     help="override width (e.g. ~100M-param e2e run)")
     ap.add_argument("--layers", type=int, default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, args.preset)
     if args.d_model:

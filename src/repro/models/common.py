@@ -48,7 +48,9 @@ def init_param(key: jax.Array, spec: ParamSpec, dtype) -> jax.Array:
             jnp.arange(1, d_state + 1, dtype=jnp.float32), spec.shape)
         return jnp.log(a).astype(dt)   # stored as log(-A)
     if spec.init == "scaled":
-        fan_in = spec.shape[0] if spec.shape else 1
+        # fan-in is the contraction dim, second from last: a leading dim
+        # is the layer stack, experts or heads, never the fan-in
+        fan_in = spec.shape[-2] if len(spec.shape) >= 2 else 1
         return (jax.random.normal(key, spec.shape, jnp.float32)
                 * (spec.scale / math.sqrt(max(1, fan_in)))).astype(dt)
     return (jax.random.normal(key, spec.shape, jnp.float32) * spec.scale).astype(dt)

@@ -136,7 +136,6 @@ def embed_tokens(params, tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
     mesh, rules = ctx
     from jax.sharding import PartitionSpec as P
 
-    from ..core.compat import shard_map
 
     v_shard = Vp // model_size
     scatter_seq = rules.get("act_seq") == "model" and T % model_size == 0
@@ -156,7 +155,7 @@ def embed_tokens(params, tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
     batch_ax = rules.get("batch")
     tok_spec = P(batch_ax, None)
     out_spec = P(batch_ax, "model" if scatter_seq else None, None)
-    x = shard_map(
+    x = jax.shard_map(
         local, mesh=mesh,
         in_specs=(pspec(("vocab", None), rules), tok_spec),
         out_specs=out_spec,

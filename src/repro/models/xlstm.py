@@ -304,7 +304,6 @@ def _shardmapped_scan(run_scan, wx, r_gates, state):
     over "data" *per time step* — measured 24.7k collectives/step on
     xlstm train_4k. Inside shard_map the per-shard cotangents accumulate
     locally and a single psum fires at the boundary."""
-    from ..core.compat import shard_map
     from ..sharding.rules import _CTX
     from jax.sharding import PartitionSpec as P
 
@@ -322,14 +321,13 @@ def _shardmapped_scan(run_scan, wx, r_gates, state):
     def wrapped(wx_in, r_in, st0):
         # mark the weight *varying* before the scan: its cotangent then
         # accumulates shard-locally across all T steps and the psum fires
-        # once at the pvary boundary (outside the loop) instead of
+        # once at the pcast boundary (outside the loop) instead of
         # per-step (jax emits psum_invariant inside the while body for
         # invariant inputs — measured 24.6k in-loop all-reduces).
-        if hasattr(jax.lax, "pvary"):
-            r_in = jax.lax.pvary(r_in, axes_flat)
+        r_in = jax.lax.pcast(r_in, axes_flat, to="varying")
         return run_scan(wx_in, r_in, st0)
 
-    return shard_map(
+    return jax.shard_map(
         wrapped, mesh=mesh,
         in_specs=(bspec3, P(), (sspec, sspec, sspec, sspec)),
         out_specs=((sspec, sspec, sspec, sspec),

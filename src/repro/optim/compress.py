@@ -15,7 +15,6 @@ from typing import Any, Dict, Tuple
 
 import jax
 
-from ..core import compat
 import jax.numpy as jnp
 
 
@@ -39,7 +38,7 @@ def init_error(params) -> Dict[str, Any]:
 def compressed_psum(grads, errors, axis_name: str):
     """psum(grads) over the DP axis with int8 error-feedback compression.
     Returns (reduced grads, new errors). Call inside shard_map."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
 
     def one(g, e):
         q, scale, e_new = compress(g.astype(jnp.float32), e)

@@ -67,14 +67,9 @@ def make_rules(
 @contextlib.contextmanager
 def sharding_context(mesh: Mesh, rules: Dict[str, Any]):
     token = _CTX.set((mesh, rules))
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
     try:
-        if use_mesh is not None:
-            with use_mesh(mesh):
-                yield
-        else:
-            with mesh:
-                yield
+        with mesh:
+            yield
     finally:
         _CTX.reset(token)
 

@@ -15,34 +15,34 @@ def test_ring_collectives_match_lax(subproc):
     out = subproc(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.core.compat import make_mesh, shard_map
+        from repro.core.compat import make_mesh
         from repro.comm import ring
 
         mesh = make_mesh((8,), ("r",))
         x = jnp.arange(8 * 16 * 4, dtype=jnp.float32).reshape(8 * 16, 4)
 
         for schedule in ("serial", "overlap"):
-            ag = jax.jit(shard_map(
+            ag = jax.jit(jax.shard_map(
                 lambda s: ring.ring_all_gather(s, "r", schedule=schedule),
                 mesh=mesh, in_specs=P("r", None), out_specs=P("r", None)))(x)
             # every shard gathers the full array; out_specs P('r') stacks
             # shard 0's copy first: compare against plain tile
-            ref = jax.jit(shard_map(
+            ref = jax.jit(jax.shard_map(
                 lambda s: jax.lax.all_gather(s, "r", axis=0, tiled=True),
                 mesh=mesh, in_specs=P("r", None), out_specs=P("r", None)))(x)
             assert jnp.allclose(ag, ref), schedule
 
-            ar = jax.jit(shard_map(
+            ar = jax.jit(jax.shard_map(
                 lambda s: ring.ring_all_reduce(s, "r", schedule=schedule),
                 mesh=mesh, in_specs=P("r", None), out_specs=P("r", None)))(x)
-            ar_ref = jax.jit(shard_map(
+            ar_ref = jax.jit(jax.shard_map(
                 lambda s: jax.lax.psum(s, "r"),
                 mesh=mesh, in_specs=P("r", None), out_specs=P("r", None)))(x)
             assert jnp.allclose(ar, ar_ref, rtol=1e-6), schedule
 
         # fused all-gather matmul: every shard ends with the full product
         w = jnp.ones((4, 8), jnp.float32) * 0.5
-        agm = jax.jit(shard_map(
+        agm = jax.jit(jax.shard_map(
             lambda s, w: ring.overlap_matmul_allgather(s, w, "r"),
             mesh=mesh, in_specs=(P("r", None), P(None, None)),
             out_specs=P("r", None)))(x, w)
@@ -51,7 +51,7 @@ def test_ring_collectives_match_lax(subproc):
             "overlap_matmul_allgather"
 
         # reduce_scatter matmul
-        rsm = jax.jit(shard_map(
+        rsm = jax.jit(jax.shard_map(
             lambda s, w: ring.reduce_scatter_matmul(s, w, "r"),
             mesh=mesh, in_specs=(P(None, None), P(None, None)),
             out_specs=P("r", None)))(x[:16], w)

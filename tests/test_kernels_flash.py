@@ -25,7 +25,7 @@ def test_shapes_dtypes(B, T, H, D, dtype):
     q = rand((B, T, H, D), dtype, 1)
     k = rand((B, T, H, D), dtype, 2)
     v = rand((B, T, H, D), dtype, 3)
-    out = flash_attention(q, k, v, block_q=64, block_k=64)
+    out = flash_attention(q, k, v, block_q=64, block_k=64, interpret=True)
     ref = mha_reference(q.astype(jnp.float32), k.astype(jnp.float32),
                         v.astype(jnp.float32))
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
@@ -38,7 +38,7 @@ def test_gqa_expansion():
     q = rand((B, T, H, D), jnp.float32, 1)
     k = rand((B, T, K, D), jnp.float32, 2)
     v = rand((B, T, K, D), jnp.float32, 3)
-    out = flash_attention(q, k, v, block_q=64, block_k=64)
+    out = flash_attention(q, k, v, block_q=64, block_k=64, interpret=True)
     kx = jnp.repeat(k, H // K, axis=2)
     vx = jnp.repeat(v, H // K, axis=2)
     ref = mha_reference(q, kx, vx)
@@ -51,7 +51,8 @@ def test_sliding_window(window):
     q = rand((B, T, H, D), jnp.float32, 1)
     k = rand((B, T, H, D), jnp.float32, 2)
     v = rand((B, T, H, D), jnp.float32, 3)
-    out = flash_attention(q, k, v, window=window, block_q=64, block_k=64)
+    out = flash_attention(q, k, v, window=window, block_q=64, block_k=64,
+                          interpret=True)
     ref = mha_reference(q, k, v, window=window)
     assert float(jnp.abs(out - ref).max()) < 2e-5
 
@@ -61,7 +62,8 @@ def test_non_causal():
     q = rand((B, T, H, D), jnp.float32, 1)
     k = rand((B, T, H, D), jnp.float32, 2)
     v = rand((B, T, H, D), jnp.float32, 3)
-    out = flash_attention(q, k, v, causal=False, block_q=64, block_k=64)
+    out = flash_attention(q, k, v, causal=False, block_q=64, block_k=64,
+                          interpret=True)
     ref = mha_reference(q, k, v, causal=False)
     assert float(jnp.abs(out - ref).max()) < 2e-5
 
@@ -73,7 +75,8 @@ def test_gradients_match_reference():
     v = rand((B, T, H, D), jnp.float32, 3)
 
     def loss_flash(q, k, v):
-        return (flash_attention(q, k, v, block_q=64, block_k=64) ** 2).sum()
+        return (flash_attention(q, k, v, block_q=64, block_k=64,
+                                interpret=True) ** 2).sum()
 
     def loss_ref(q, k, v):
         return (mha_reference(q, k, v) ** 2).sum()
@@ -93,7 +96,7 @@ def test_windowed_gradients():
 
     def lf(q, k, v):
         return (flash_attention(q, k, v, window=48, block_q=64,
-                                block_k=64) ** 2).sum()
+                                block_k=64, interpret=True) ** 2).sum()
 
     def lr(q, k, v):
         return (mha_reference(q, k, v, window=48) ** 2).sum()
@@ -116,6 +119,7 @@ def test_property_sweep(T, D, H, causal):
     q = rand((1, T, H, D), jnp.float32, T + D)
     k = rand((1, T, H, D), jnp.float32, T + D + 1)
     v = rand((1, T, H, D), jnp.float32, T + D + 2)
-    out = flash_attention(q, k, v, causal=causal, block_q=32, block_k=32)
+    out = flash_attention(q, k, v, causal=causal, block_q=32, block_k=32,
+                          interpret=True)
     ref = mha_reference(q, k, v, causal=causal)
     assert float(jnp.abs(out - ref).max()) < 2e-5

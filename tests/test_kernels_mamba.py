@@ -28,14 +28,16 @@ def inputs(B, T, dI, N, dtype=jnp.float32):
 ])
 def test_shapes(B, T, dI, N):
     x, dt, A, Bc, Cc, D = inputs(B, T, dI, N)
-    out = mamba_scan(x, dt, A, Bc, Cc, D, block_d=32, block_t=32)
+    out = mamba_scan(x, dt, A, Bc, Cc, D, block_d=32, block_t=32,
+                     interpret=True)
     ref = selective_scan_reference(x, dt, A, Bc, Cc, D)
     assert float(jnp.abs(out - ref).max()) < 1e-4
 
 
 def test_bf16_inputs():
     x, dt, A, Bc, Cc, D = inputs(1, 64, 64, 8, dtype=jnp.bfloat16)
-    out = mamba_scan(x, dt, A, Bc, Cc, D, block_d=32, block_t=32)
+    out = mamba_scan(x, dt, A, Bc, Cc, D, block_d=32, block_t=32,
+                     interpret=True)
     ref = selective_scan_reference(x, dt, A, Bc, Cc, D)
     assert float(jnp.abs(out.astype(jnp.float32) - ref).max()) < 5e-2
 
@@ -43,9 +45,11 @@ def test_bf16_inputs():
 def test_state_carries_across_time_blocks():
     # output at t > block_t must depend on inputs before the block boundary
     x, dt, A, Bc, Cc, D = inputs(1, 64, 32, 4)
-    out1 = mamba_scan(x, dt, A, Bc, Cc, D, block_d=32, block_t=16)
+    out1 = mamba_scan(x, dt, A, Bc, Cc, D, block_d=32, block_t=16,
+                      interpret=True)
     x2 = x.at[:, 0].set(x[:, 0] + 10.0)
-    out2 = mamba_scan(x2, dt, A, Bc, Cc, D, block_d=32, block_t=16)
+    out2 = mamba_scan(x2, dt, A, Bc, Cc, D, block_d=32, block_t=16,
+                      interpret=True)
     assert float(jnp.abs(out1[:, 32:] - out2[:, 32:]).max()) > 0
 
 
@@ -54,6 +58,7 @@ def test_state_carries_across_time_blocks():
        st.sampled_from([4, 8]))
 def test_property_sweep(T, dI, N):
     x, dt, A, Bc, Cc, D = inputs(1, T, dI, N)
-    out = mamba_scan(x, dt, A, Bc, Cc, D, block_d=16, block_t=16)
+    out = mamba_scan(x, dt, A, Bc, Cc, D, block_d=16, block_t=16,
+                     interpret=True)
     ref = selective_scan_reference(x, dt, A, Bc, Cc, D)
     assert float(jnp.abs(out - ref).max()) < 1e-4

@@ -292,7 +292,7 @@ def test_comm_layer_routes_through_fabric(subproc):
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
         from repro.comm import collectives, ring
-        from repro.core.compat import make_mesh, shard_map
+        from repro.core.compat import make_mesh
         from repro.core.counters import CounterRegistry
         from repro.match import Fabric
 
@@ -300,10 +300,10 @@ def test_comm_layer_routes_through_fabric(subproc):
         collectives.configure_matching(Fabric(mode="binned", registry=reg))
         mesh = make_mesh((8,), ("r",))
         x = jnp.arange(8 * 4, dtype=jnp.float32).reshape(8, 4)
-        jax.jit(shard_map(lambda s: ring.ring_all_gather(s, "r"),
+        jax.jit(jax.shard_map(lambda s: ring.ring_all_gather(s, "r"),
                           mesh=mesh, in_specs=P("r", None),
                           out_specs=P("r", None)))(x)
-        jax.jit(shard_map(lambda s: collectives.psum(s, "r"),
+        jax.jit(jax.shard_map(lambda s: collectives.psum(s, "r"),
                           mesh=mesh, in_specs=P("r", None),
                           out_specs=P(None, None)))(x)
         collectives.configure_matching(None)

@@ -148,3 +148,24 @@ def test_windowed_cache_smaller_than_full():
     k5 = shapes["pos5"]["mixer"]["k"].shape
     assert k0[2] == min(4096, win)
     assert k5[2] == 4096
+
+
+def test_scaled_init_uses_contraction_fan_in():
+    # a stacked (layers, fan_in, out) projection: the leading layer dim
+    # must not stand in for the fan-in
+    from repro.models.common import ParamSpec, init_param
+    spec = ParamSpec((6, 1536, 768), ("layers", None, "embed"),
+                     init="scaled", scale=1.0)
+    w = init_param(KEY, spec, jnp.float32)
+    assert abs(float(w.std()) * np.sqrt(1536) - 1.0) < 0.01
+
+
+def test_slstm_gradient_bounded_over_long_sequence():
+    # the sLSTM recurrence is backpropagated through 1024 steps; with the
+    # recurrent weights initialized too large the gradient norm explodes
+    cfg = get_config("xlstm-125m", "smoke")
+    params = M.init_params(KEY, cfg)
+    opt_cfg = adamw.AdamWConfig(total_steps=2, warmup_steps=1)
+    step = jax.jit(make_train_step(cfg, opt_cfg))
+    _, _, m = step(params, adamw.init_state(params), batch_for(cfg, 1, 1024))
+    assert float(m["grad_norm"]) < 10.0

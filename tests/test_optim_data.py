@@ -75,11 +75,11 @@ def test_compression_error_feedback_contracts(values):
 def test_compressed_psum_single_device():
     # axis size 1: compressed psum == identity up to quantization
     from jax.sharding import PartitionSpec as P
-    from repro.core.compat import make_mesh, shard_map
+    from repro.core.compat import make_mesh
     mesh = make_mesh((1,), ("d",))
     g = {"w": jnp.array([1.0, -2.0, 3.0])}
     e = compress.init_error(g)
-    out, _ = jax.jit(shard_map(
+    out, _ = jax.jit(jax.shard_map(
         lambda g, e: compress.compressed_psum(g, e, "d"),
         mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P())))(g, e)
     assert np.allclose(np.asarray(out["w"]), np.asarray(g["w"]), atol=0.05)
