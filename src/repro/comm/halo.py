@@ -10,9 +10,24 @@ sizes. The TPU-meaningful axes of that design space:
     flight; apply boundary columns afterwards (comm hidden behind compute).
   * width, box          — message size sweep (COMB's size sweeps).
 
-Regions are named after COMB's own Caliper annotations (pre-comm,
-post-send, wait-recv, post-comm, ...) so the comparison trees in
-benchmarks/ read like the paper's Figures 1-3.
+Two kinds of names mark the program's phases, one per side of the host /
+device boundary:
+
+  * Host regions (:mod:`repro.core.regions`) time what the host does.
+    They are named after COMB's own Caliper annotations (bench_comm,
+    pre-comm, post-send, post-comm, wait-recv, post-recv, wait-send), so
+    the comparison trees in benchmarks/ read like the paper's Figures
+    1-3. Only :class:`HaloProgram` opens them: it dispatches one jitted
+    segment per phase, so a region there times real work. Inside one
+    ``jit`` a region would fire once, while the function is traced.
+  * Device scopes (``jax.named_scope``) name the ops XLA emits: every
+    instruction's ``op_name`` metadata, and so every op of a profiler
+    trace, carries one of ``halo.extract`` (face slicing),
+    ``halo.exchange`` (the ppermutes), ``halo.interior``
+    (:func:`stencil_interior`) and ``halo.boundary``
+    (:func:`_apply_halos`). The fused program (:func:`make_halo_fn`, both
+    variants), :func:`make_xla_auto_fn` and the segments of
+    :class:`HaloProgram` carry the same four.
 """
 from __future__ import annotations
 
@@ -38,86 +53,71 @@ def _shift(x: jax.Array, axis_name: str, direction: int,
                     tag=patterns.halo_tag(ax, direction))
 
 
+_AXIS = {"x": 0, "y": 1, "z": 2}
+
+
+def _face_index(ax: int, width: int, front: bool) -> tuple:
+    idx = [slice(None)] * 3
+    idx[ax] = slice(0, width) if front else slice(-width, None)
+    return tuple(idx)
+
+
+def extract_faces(u: jax.Array, axis_names, width: int
+                  ) -> Dict[str, Tuple[jax.Array, jax.Array]]:
+    """The (lo, hi) face of the local block along each mesh axis."""
+    with jax.named_scope("halo.extract"):
+        return {name: (u[_face_index(_AXIS[name], width, True)],
+                       u[_face_index(_AXIS[name], width, False)])
+                for name in axis_names}
+
+
+def exchange_faces(faces, axis_names) -> Dict[str, Tuple[jax.Array,
+                                                         jax.Array]]:
+    """Receive the neighbor's hi face as my lo halo and vice versa."""
+    with jax.named_scope("halo.exchange"), comm_phase("halo_exchange"):
+        return {name: (_shift(faces[name][1], name, +1, ax=i),
+                       _shift(faces[name][0], name, -1, ax=i))
+                for i, name in enumerate(axis_names)}
+
+
 def stencil_interior(u: jax.Array) -> jax.Array:
     """7-point Laplacian on the local block (interior only; edges wrong
     until halos are applied)."""
-    return (
-        -6.0 * u
-        + jnp.roll(u, 1, 0) + jnp.roll(u, -1, 0)
-        + jnp.roll(u, 1, 1) + jnp.roll(u, -1, 1)
-        + jnp.roll(u, 1, 2) + jnp.roll(u, -1, 2)
-    )
+    with jax.named_scope("halo.interior"):
+        return (
+            -6.0 * u
+            + jnp.roll(u, 1, 0) + jnp.roll(u, -1, 0)
+            + jnp.roll(u, 1, 1) + jnp.roll(u, -1, 1)
+            + jnp.roll(u, 1, 2) + jnp.roll(u, -1, 2)
+        )
 
 
 def _apply_halos(out, u, halos, width: int):
     """Fix the wrap-around faces of the rolled stencil with true halos."""
-    w = width
-    for axis, (lo, hi) in halos.items():
-        ax = {"x": 0, "y": 1, "z": 2}[axis]
-
-        def face(arr, front: bool):
-            idx = [slice(None)] * 3
-            idx[ax] = slice(0, w) if front else slice(-w, None)
-            return arr[tuple(idx)]
-
-        # replace the wrong wrap contribution with the neighbor's face
-        def fix(front, halo):
-            nonlocal out
-            idx = [slice(None)] * 3
-            idx[ax] = slice(0, w) if front else slice(-w, None)
-            wrong = face(jnp.roll(u, 1 if front else -1, ax), front)
-            corr = face(out, front) - wrong + halo
-            out = out.at[tuple(idx)].set(corr)
-
-        fix(True, lo)
-        fix(False, hi)
-    return out
+    with jax.named_scope("halo.boundary"):
+        for axis, (lo, hi) in halos.items():
+            ax = _AXIS[axis]
+            # replace the wrong wrap contribution with the neighbor's face
+            for front, halo in ((True, lo), (False, hi)):
+                idx = _face_index(ax, width, front)
+                wrong = jnp.roll(u, 1 if front else -1, ax)[idx]
+                out = out.at[idx].set(out[idx] - wrong + halo)
+        return out
 
 
 def halo_step(u: jax.Array, axis_names=("x", "y", "z"), width: int = 1,
               variant: str = "overlap") -> jax.Array:
     """One stencil step with halo exchange on the local block (in shard_map)."""
-    w = width
-    halos: Dict[str, Tuple[jax.Array, jax.Array]] = {}
-
-    with regions.annotate("bench_comm", category="app"):
-        with regions.annotate("pre-comm", category="api"):
-            faces = {}
-            for name in axis_names:
-                ax = {"x": 0, "y": 1, "z": 2}[name]
-                idx_lo = [slice(None)] * 3
-                idx_lo[ax] = slice(0, w)
-                idx_hi = [slice(None)] * 3
-                idx_hi[ax] = slice(-w, None)
-                faces[name] = (u[tuple(idx_lo)], u[tuple(idx_hi)])
-
-        with regions.annotate("post-send", category="api"), \
-                comm_phase("halo_exchange"):
-            for i, name in enumerate(axis_names):
-                lo_face, hi_face = faces[name]
-                # receive the neighbor's hi face as my lo halo and vice versa
-                halos[name] = (
-                    _shift(hi_face, name, +1, ax=i),
-                    _shift(lo_face, name, -1, ax=i),
-                )
-
-        if variant == "blocking":
-            with regions.annotate("wait-recv", category="api"):
-                # one queue: pin compute behind the completed exchange
-                flat, tree = jax.tree.flatten(halos)
-                flat = list(jax.lax.optimization_barrier(tuple(flat)))
-                u_b = jax.lax.optimization_barrier(u)
-                halos = jax.tree.unflatten(tree, flat)
-            with regions.annotate("post-comm", category="api"):
-                out = stencil_interior(u_b)
-                out = _apply_halos(out, u_b, halos, w)
-        else:
-            with regions.annotate("post-comm", category="api"):
-                # second queue: interior stencil runs while faces fly
-                out = stencil_interior(u)
-            with regions.annotate("wait-recv", category="api"):
-                out = _apply_halos(out, u, halos, w)
-    return out
+    halos = exchange_faces(extract_faces(u, axis_names, width), axis_names)
+    if variant == "blocking":
+        with jax.named_scope("halo.exchange"):
+            # one queue: pin compute behind the completed exchange
+            flat, tree = jax.tree.flatten(halos)
+            flat = list(jax.lax.optimization_barrier(tuple(flat)))
+            u = jax.lax.optimization_barrier(u)
+            halos = jax.tree.unflatten(tree, flat)
+    # overlap: the interior stencil runs while faces fly
+    return _apply_halos(stencil_interior(u), u, halos, width)
 
 
 def make_halo_fn(mesh: Mesh, width: int = 1, variant: str = "overlap",
@@ -163,26 +163,10 @@ class HaloProgram:
         w = width
 
         def extract(u):
-            faces = {}
-            for name in axes:
-                ax = {"x": 0, "y": 1, "z": 2}[name]
-                idx_lo = [slice(None)] * 3
-                idx_lo[ax] = slice(0, w)
-                idx_hi = [slice(None)] * 3
-                idx_hi[ax] = slice(-w, None)
-                faces[name] = (u[tuple(idx_lo)], u[tuple(idx_hi)])
-            return faces
+            return extract_faces(u, axes, w)
 
         def exchange(faces):
-            halos = {}
-            with comm_phase("halo_exchange"):
-                for i, name in enumerate(axes):
-                    lo_face, hi_face = faces[name]
-                    halos[name] = (
-                        _shift(hi_face, name, +1, ax=i),
-                        _shift(lo_face, name, -1, ax=i),
-                    )
-            return halos
+            return exchange_faces(faces, axes)
 
         def interior(u):
             return stencil_interior(u)
@@ -224,7 +208,6 @@ class HaloProgram:
         self._exchange_takes_u = not explicit
 
     def step(self, u, engine=None, fence_every_op: bool = False):
-        from ..core import regions
         fence = jax.block_until_ready if fence_every_op else (lambda x: x)
         ex_arg = u if self._exchange_takes_u else None
         with regions.annotate("bench_comm", category="app"):
@@ -254,7 +237,6 @@ class HaloProgram:
         return out
 
     def run(self, u, steps: int, engine=None, fence_every_op: bool = False):
-        from ..core import regions
         for s in range(steps):
             with regions.annotate(f"cycle_{s}", category="app"):
                 u = self.step(u, engine=engine,
@@ -268,19 +250,9 @@ def make_xla_auto_fn(mesh: Mesh, width: int = 1, steps: int = 1):
     """The 'vendor' implementation: plain jnp.roll on a sharded global
     array — GSPMD chooses the collectives (Spectrum-MPI analog)."""
 
-    def step(u):
-        with regions.annotate("bench_comm", category="app"):
-            with regions.annotate("post-comm", category="api"):
-                return (
-                    -6.0 * u
-                    + jnp.roll(u, 1, 0) + jnp.roll(u, -1, 0)
-                    + jnp.roll(u, 1, 1) + jnp.roll(u, -1, 1)
-                    + jnp.roll(u, 1, 2) + jnp.roll(u, -1, 2)
-                )
-
     def run(u):
         for _ in range(steps):
-            u = step(u)
+            u = stencil_interior(u)
         return u
 
     return run

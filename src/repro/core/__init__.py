@@ -15,7 +15,7 @@ from .comparison import (ComparisonResult, ProfileReport, ReportRow,
                          compare, compare_frames, profile_runs)
 from .events import Event
 from .graphframe import GraphFrame
-from .regions import annotate, annotate_jax, configure, profiled
+from .regions import annotate, configure, profiled
 from .roofline import HW, Roofline
 
 __all__ = [
@@ -26,6 +26,6 @@ __all__ = [
     "reduce_lanes", "reset_global_registry",
     "ComparisonResult", "ProfileReport", "ReportRow", "compare",
     "compare_frames", "profile_runs", "Event",
-    "GraphFrame", "annotate", "annotate_jax", "configure", "profiled",
+    "GraphFrame", "annotate", "configure", "profiled",
     "HW", "Roofline",
 ]

@@ -37,6 +37,7 @@ _DEF_RE = re.compile(
 _REPLICA_GROUPS_RE = re.compile(r"replica_groups=\{(\{[0-9,{} ]*\})\}")
 _REPLICA_GROUPS_IOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
 _SOURCE_TARGET_RE = re.compile(r"source_target_pairs=\{([^}]*(?:\},\{[^}]*)*)\}")
+_OP_NAME_RE = re.compile(r'metadata=\{[^}]*?\bop_name="((?:[^"\\]|\\.)*)"')
 
 
 _OP_START_RE = re.compile(r"^\s*(?:ROOT\s+)?%[\w.\-]+\s*=")
@@ -266,6 +267,21 @@ def collective_stats(hlo_text: str) -> CollectiveStats:
         by_opcode=dict(by),
         async_count=sum(1 for o in ops if o.is_async),
     )
+
+
+def op_scopes(hlo_text: str) -> Dict[str, str]:
+    """instruction name -> ``op_name`` for every instruction of a module
+    whose metadata carries one: the path of ``jax.named_scope``s (and the
+    primitive) that emitted it, e.g. ``jit(f)/halo.interior/add``. A
+    fusion carries its own metadata, which XLA sets from its root op; a
+    profiler trace names device ops by these instruction names."""
+    out: Dict[str, str] = {}
+    for line in logical_lines(hlo_text):
+        m = _DEF_RE.match(line)
+        n = _OP_NAME_RE.search(line) if m else None
+        if n:
+            out[m.group(1)] = n.group(1)
+    return out
 
 
 _WHILE_TRIP_RE = re.compile(r"trip_count=\"?(\d+)")

@@ -8,6 +8,14 @@
 Regions nest; the full path is recorded per event, which is what lets the
 GraphFrame reconstruct the hierarchical context tree (paper Fig. 1).
 
+While a JAX profiler trace is active (``jax.profiler.start_trace``), every
+region is mirrored into it as a ``jax.profiler.TraceAnnotation`` named
+``repro.<region name>`` (``repro.wait-recv``, ``repro.BlockingProgress
+lock``): a host span on its own thread's line, nested as the region stack
+is, on the clock of the trace's device events. The collector's event is
+written as without a trace. With no trace active the mirror costs one
+``TraceAnnotation.is_enabled()`` check per region and builds nothing.
+
 Categories mirror ExaMPI's runtime-configurable profiling groups (§4.2):
 profiling of each category can be switched on/off at runtime to bound
 overhead and trace size. The default category set used by the framework:
@@ -24,23 +32,23 @@ import contextlib
 import functools
 import threading
 import time
-from typing import Any, Dict, Iterator, Optional, Set
+from typing import Any, Iterator, Optional, Set
+
+from jax.profiler import TraceAnnotation
 
 from .collector import Collector, global_collector
 from .events import Event
 
 DEFAULT_CATEGORIES = ("app", "api", "collective", "runtime", "data")
+TRACE_PREFIX = "repro."          # profiler-trace name of a mirrored region
 
 
 class ProfilingConfig:
-    """Runtime profiling configuration (which categories are live, fencing)."""
+    """Runtime profiling configuration (which categories are live)."""
 
-    def __init__(self, categories: Optional[Set[str]] = None, fence: bool = False):
+    def __init__(self, categories: Optional[Set[str]] = None):
         # None => everything enabled
         self.categories: Optional[Set[str]] = categories
-        # fence=True => regions wrapping jax dispatch should block_until_ready
-        # ("fenced" timing measures completion; unfenced measures dispatch).
-        self.fence = fence
 
     def enabled(self, category: str) -> bool:
         return self.categories is None or category in self.categories
@@ -53,14 +61,13 @@ _tls = threading.local()
 _UNSET = object()
 
 
-def configure(categories=_UNSET, fence=_UNSET) -> None:
+def configure(categories=_UNSET) -> None:
     """Runtime re-configuration, like ExaMPI's profiling level toggles.
     ``categories=None`` enables everything; a set enables only those."""
     global _config
-    cats = (_config.categories if categories is _UNSET
-            else (set(categories) if categories is not None else None))
-    fn = _config.fence if fence is _UNSET else bool(fence)
-    _config = ProfilingConfig(categories=cats, fence=fn)
+    if categories is not _UNSET:
+        _config = ProfilingConfig(
+            categories=set(categories) if categories is not None else None)
 
 
 def config() -> ProfilingConfig:
@@ -95,13 +102,19 @@ def annotate(
         yield
         return
     col = collector or global_collector()
+    mirror = (TraceAnnotation(TRACE_PREFIX + name)
+              if TraceAnnotation.is_enabled() else None)
     st = _stack()
     st.append((name, category))
+    if mirror is not None:
+        mirror.__enter__()
     t0 = clock_ns()
     try:
         yield
     finally:
         t1 = clock_ns()
+        if mirror is not None:
+            mirror.__exit__(None, None, None)
         path = tuple(n for n, _c in st)
         st.pop()
         col.emit(
@@ -132,27 +145,3 @@ def profiled(name: Optional[str] = None, category: str = "app", **attrs: Any):
         return wrapper
 
     return deco
-
-
-@contextlib.contextmanager
-def annotate_jax(
-    name: str,
-    category: str = "api",
-    collector: Optional[Collector] = None,
-    **attrs: Any,
-) -> Iterator[Dict[str, Any]]:
-    """Region for code that dispatches JAX computations.
-
-    If ``config().fence`` is set, the caller should place its outputs in the
-    yielded dict under ``"out"``; the region then blocks until those arrays
-    are ready, so the recorded time is *completion* time, not dispatch time
-    (the distinction the paper draws between MPI_Isend enqueue cost and the
-    progress thread's completion work).
-    """
-    box: Dict[str, Any] = {}
-    with annotate(name, category=category, collector=collector, **attrs):
-        yield box
-        if _config.fence and "out" in box:
-            import jax
-
-            jax.block_until_ready(box["out"])

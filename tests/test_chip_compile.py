@@ -9,6 +9,7 @@ process may load the TPU library, and every test worker imports this
 file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +18,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.comm.halo import make_halo_fn
+from repro.core import hlo
 from repro.core.compat import make_mesh
 from repro.kernels.flash_attention.kernel import (flash_attention_bwd,
                                                   flash_attention_fwd)
@@ -93,13 +95,47 @@ def test_selective_scan_compiles(one_chip):
     assert _fits(compiled)
 
 
-@pytest.mark.parametrize("variant", ["overlap", "blocking"])
-def test_halo_step_compiles(topo, variant):
-    # a 512^3 float32 box (512 MiB field, 1 MiB faces) on one chip
+@pytest.fixture(scope="module")
+def halo_512(topo):
+    """The fused 4-step halo program on a 512^3 float32 box (512 MiB
+    field, 1 MiB faces) on one chip, compiled once per variant."""
     mesh = make_mesh((1, 1, 1), ("x", "y", "z"), devices=topo.devices[:1])
     u = _spec((512, 512, 512), jnp.float32,
               NamedSharding(mesh, P("x", "y", "z")))
-    compiled = jax.jit(make_halo_fn(mesh, variant=variant, steps=4)
-                       ).lower(u).compile()
+    compiled = {}
+
+    def get(variant):
+        if variant not in compiled:
+            compiled[variant] = jax.jit(make_halo_fn(
+                mesh, variant=variant, steps=4)).lower(u).compile()
+        return compiled[variant]
+    return get
+
+
+@pytest.mark.parametrize("variant", ["overlap", "blocking"])
+def test_halo_step_compiles(halo_512, variant):
+    compiled = halo_512(variant)
     assert "collective-permute" in compiled.as_text()
     assert _fits(compiled)
+
+
+_OPCODE = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = .*?\s"
+                     r"(fusion|collective-permute(?:-start|-done)?)\(")
+
+
+@pytest.mark.parametrize("variant", ["overlap", "blocking"])
+def test_halo_ops_carry_their_scope(halo_512, variant):
+    """Every fusion and collective-permute the chip's compiler emits for
+    the halo step names one of the program's halo.* scopes in its
+    op_name, which the profiler trace carries to each device op."""
+    text = halo_512(variant).as_text()
+    scopes = hlo.op_scopes(text)
+    ops = [m.groups() for m in map(_OPCODE.match, hlo.logical_lines(text))
+           if m]
+    assert {"fusion", "collective-permute-start"} <= {op for _, op in ops}
+    for name, op in ops:
+        parts = scopes.get(name, "").split("/")
+        scope = [p for p in parts if p.startswith("halo.")]
+        assert scope, (name, op, scopes.get(name))
+        if op.startswith("collective-permute"):
+            assert scope == ["halo.exchange"], (name, scopes[name])

@@ -77,3 +77,37 @@ def test_compile_cache_defaults_to_repo(monkeypatch):
         jax.config.update("jax_compilation_cache_dir", before)
     with open(os.path.join(REPO, ".gitignore")) as f:
         assert ".jax_cache/" in f.read().splitlines()
+
+
+def test_compile_cache_keys_on_scopes(subproc, tmp_path):
+    """Two versions of a program that differ only in a named scope do not
+    share a cache entry (each runs under its own op names); the same
+    version compiled again does."""
+    out = subproc(textwrap.dedent(f"""
+        import os
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = {str(tmp_path)!r}
+        import jax, jax.numpy as jnp
+        import jax.monitoring as mon
+        from repro.core import compile_cache, hlo
+        compile_cache.enable_compile_cache()
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        hits = []
+        mon.register_event_duration_secs_listener(
+            lambda e, d, **kw: hits.append(e) if "cache_retrieval" in e
+            else None)
+
+        def version(scope):
+            def step(u):
+                with jax.named_scope(scope):
+                    return jnp.sin(u) * 2.0
+            return step
+
+        u = jnp.ones((64, 64))
+        for scope in ("halo.old", "halo.new", "halo.new"):
+            n = len(hits)
+            text = jax.jit(version(scope)).lower(u).compile().as_text()
+            names = set(hlo.op_scopes(text).values())
+            print(scope, len(hits) - n, any(scope in x for x in names))
+    """), devices=1)
+    assert out.split("\n")[:3] == ["halo.old 0 True", "halo.new 0 True",
+                                   "halo.new 1 True"]

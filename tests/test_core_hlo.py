@@ -81,6 +81,67 @@ def test_op_histogram():
     assert hist["tuple"] >= 2
 
 
+SCOPED = textwrap.dedent("""\
+    HloModule jit_local, is_scheduled=true
+
+    %fused_computation (p: f32[8,8]) -> f32[8,8] {
+      %p = f32[8,8] parameter(0)
+      ROOT %add.3 = f32[8,8] add(%p, %p), metadata={op_name="jit(local)/halo.interior/add" source_file="halo.py" source_line=77}
+    }
+
+    ENTRY %main (u: f32[8,8]) -> f32[8,8] {
+      %u = f32[8,8] parameter(0), metadata={op_name="u"}
+      %cp = (f32[1,8], f32[1,8],
+        u32[]) collective-permute-start(%u), source_target_pairs={{0,1},{1,0}}, metadata={op_type="ppermute" op_name="jit(local)/halo.exchange/comm_ppermute_x/ppermute"}
+      %copy-start = (f32[8,8], f32[8,8], u32[]) copy-start(%u), cross_program_prefetch_index=0
+      ROOT %fusion.4 = f32[8,8] fusion(%u), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(local)/halo.interior/add" source_file="halo.py" source_line=77}
+    }
+    """)
+
+
+def test_op_scopes_canned():
+    scopes = hlo.op_scopes(SCOPED)
+    assert scopes == {
+        "add.3": "jit(local)/halo.interior/add",
+        "u": "u",
+        "cp": "jit(local)/halo.exchange/comm_ppermute_x/ppermute",
+        "fusion.4": "jit(local)/halo.interior/add",
+    }
+    assert "copy-start" not in scopes      # no metadata, no scope
+
+
+def test_op_scopes_of_the_halo_program():
+    """Every halo program carries its four scopes into the compiled
+    module's metadata, on the CPU backend too."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.comm.halo import HaloProgram, make_halo_fn
+    from repro.core.compat import make_mesh
+
+    mesh = make_mesh((1, 1, 1), ("x", "y", "z"), devices=jax.devices()[:1])
+    u = jnp.zeros((8, 8, 8), jnp.float32)
+
+    def scopes(fn, *args):
+        names = hlo.op_scopes(fn.lower(*args).compile().as_text()).values()
+        return {part for name in names for part in name.split("/")
+                if part.startswith("halo.")}
+
+    want = {"halo.extract", "halo.exchange", "halo.interior",
+            "halo.boundary"}
+    for variant in ("overlap", "blocking"):
+        fused = jax.jit(make_halo_fn(mesh, variant=variant, steps=2))
+        assert scopes(fused, u) <= want
+        assert {"halo.interior", "halo.boundary"} <= scopes(fused, u)
+    prog = HaloProgram(mesh, explicit=True)
+    faces = prog.extract(u)
+    halos = prog.exchange(faces)
+    assert scopes(prog.extract, u) == {"halo.extract"}
+    assert scopes(prog.exchange, faces) == {"halo.exchange"}
+    assert scopes(prog.interior, u) == {"halo.interior"}
+    assert scopes(prog.boundary, u, u, halos) == {"halo.boundary"}
+
+
 def test_real_sharded_module(subproc):
     out = subproc(textwrap.dedent("""
         import jax, jax.numpy as jnp
