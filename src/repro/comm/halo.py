@@ -28,6 +28,16 @@ device boundary:
     (:func:`_apply_halos`). The fused program (:func:`make_halo_fn`, both
     variants), :func:`make_xla_auto_fn` and the segments of
     :class:`HaloProgram` carry the same four.
+
+Two stencils. Where the program holds one block per device (the fused
+program and ``HaloProgram(explicit=True)``, every chip benchmark cell),
+:func:`stencil_interior` computes the block's periodic stencil, on a TPU
+with the ``stencil7`` Pallas kernel (:mod:`repro.kernels.stencil7`): one
+pass over HBM where the rolled form takes about sixteen, and the step's
+largest cost. Where the program rolls a sharded global array
+(:func:`make_xla_auto_fn`, ``HaloProgram(explicit=False)``), the roll
+crosses shard edges and GSPMD picks the collectives; that is the vendor
+analog's meaning, so :func:`stencil_global` keeps the jnp roll there.
 """
 from __future__ import annotations
 
@@ -39,6 +49,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core import regions
+from ..kernels.stencil7.ops import stencil7, tiles as stencil7_tiles
 from . import patterns
 from .collectives import comm_phase, ppermute
 
@@ -80,16 +91,40 @@ def exchange_faces(faces, axis_names) -> Dict[str, Tuple[jax.Array,
                 for i, name in enumerate(axis_names)}
 
 
+def rolled_stencil(u: jax.Array) -> jax.Array:
+    """7-point Laplacian of ``u``, periodic on every axis, as six
+    ``jnp.roll``s: the jnp form, which XLA compiles to several passes over
+    the field."""
+    return (
+        -6.0 * u
+        + jnp.roll(u, 1, 0) + jnp.roll(u, -1, 0)
+        + jnp.roll(u, 1, 1) + jnp.roll(u, -1, 1)
+        + jnp.roll(u, 1, 2) + jnp.roll(u, -1, 2)
+    )
+
+
 def stencil_interior(u: jax.Array) -> jax.Array:
     """7-point Laplacian on the local block (interior only; edges wrong
-    until halos are applied)."""
+    until halos are applied).
+
+    Compiled for a TPU, a block whose y-z planes tile runs the
+    ``stencil7`` Pallas kernel, one pass over HBM; elsewhere (the CPU, a
+    block such as 16^3) it is :func:`rolled_stencil`. The choice is made
+    as the program is lowered, by the platform it is lowered for. Both
+    compute the same float32 sum, periodic within the block."""
     with jax.named_scope("halo.interior"):
-        return (
-            -6.0 * u
-            + jnp.roll(u, 1, 0) + jnp.roll(u, -1, 0)
-            + jnp.roll(u, 1, 1) + jnp.roll(u, -1, 1)
-            + jnp.roll(u, 1, 2) + jnp.roll(u, -1, 2)
-        )
+        if not stencil7_tiles(u.shape, u.dtype.itemsize):
+            return rolled_stencil(u)
+        return jax.lax.platform_dependent(u, tpu=stencil7,
+                                          default=rolled_stencil)
+
+
+def stencil_global(u: jax.Array) -> jax.Array:
+    """One complete periodic stencil step of a sharded *global* array:
+    :func:`rolled_stencil` rolls across shard edges, so GSPMD chooses the
+    collectives. The kernel cannot serve here: it sees one block."""
+    with jax.named_scope("halo.interior"):
+        return rolled_stencil(u)
 
 
 def _apply_halos(out, u, halos, width: int):
@@ -169,7 +204,9 @@ class HaloProgram:
             return exchange_faces(faces, axes)
 
         def interior(u):
-            return stencil_interior(u)
+            # the block's stencil under shard_map; the global array's
+            # under GSPMD
+            return stencil_interior(u) if explicit else stencil_global(u)
 
         def boundary(out, u, halos):
             return _apply_halos(out, u, halos, w)
@@ -252,7 +289,7 @@ def make_xla_auto_fn(mesh: Mesh, width: int = 1, steps: int = 1):
 
     def run(u):
         for _ in range(steps):
-            u = stencil_interior(u)
+            u = stencil_global(u)
         return u
 
     return run

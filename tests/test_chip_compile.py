@@ -17,14 +17,16 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from repro.comm.halo import make_halo_fn
+from repro.comm.halo import HaloProgram, make_halo_fn
 from repro.core import hlo
 from repro.core.compat import make_mesh
 from repro.kernels.flash_attention.kernel import (flash_attention_bwd,
                                                   flash_attention_fwd)
 from repro.kernels.mamba_scan.kernel import selective_scan
+from repro.kernels.stencil7.kernel import slab_depth, vmem_bytes
 
 HBM_BYTES = 16 * 10**9
+VMEM_BYTES = 128 * 2**20  # per v5e core
 
 
 @pytest.fixture(scope="module")
@@ -95,19 +97,32 @@ def test_selective_scan_compiles(one_chip):
     assert _fits(compiled)
 
 
+BOX = 512
+
+
 @pytest.fixture(scope="module")
-def halo_512(topo):
-    """The fused 4-step halo program on a 512^3 float32 box (512 MiB
-    field, 1 MiB faces) on one chip, compiled once per variant."""
-    mesh = make_mesh((1, 1, 1), ("x", "y", "z"), devices=topo.devices[:1])
-    u = _spec((512, 512, 512), jnp.float32,
-              NamedSharding(mesh, P("x", "y", "z")))
+def halo_mesh(topo):
+    return make_mesh((1, 1, 1), ("x", "y", "z"), devices=topo.devices[:1])
+
+
+@pytest.fixture(scope="module")
+def halo_field(halo_mesh):
+    """A 512^3 float32 box (512 MiB field, 1 MiB faces) on one chip."""
+    return _spec((BOX, BOX, BOX), jnp.float32,
+                 NamedSharding(halo_mesh, P("x", "y", "z")))
+
+
+@pytest.fixture(scope="module")
+def halo_512(halo_mesh, halo_field):
+    """The fused 4-step halo program on the 512^3 box, compiled once per
+    variant."""
     compiled = {}
 
     def get(variant):
         if variant not in compiled:
             compiled[variant] = jax.jit(make_halo_fn(
-                mesh, variant=variant, steps=4)).lower(u).compile()
+                halo_mesh, variant=variant, steps=4)).lower(
+                halo_field).compile()
         return compiled[variant]
     return get
 
@@ -120,19 +135,76 @@ def test_halo_step_compiles(halo_512, variant):
 
 
 _OPCODE = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = .*?\s"
-                     r"(fusion|collective-permute(?:-start|-done)?)\(")
+                     r"(fusion|custom-call|collective-permute"
+                     r"(?:-start|-done)?)\(")
+
+
+_ANY_OP = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = .*?\s([\w\-]+)\(")
+# ops that move no data
+_VIEWS = {"bitcast", "get-tuple-element", "tuple", "parameter"}
+
+
+def _kernel_calls(text):
+    """(instruction, op_name) of each Mosaic kernel call in ``text``."""
+    scopes = hlo.op_scopes(text)
+    return [(m.group(1), scopes.get(m.group(1), ""))
+            for line in hlo.logical_lines(text)
+            for m in [_ANY_OP.match(line)]
+            if m and m.group(2) == "custom-call" and "tpu_custom_call" in line]
+
+
+def _kernel_fits_vmem():
+    # Mosaic refuses a kernel whose scoped VMEM exceeds the limit it
+    # asks for; that limit must lie within the core's VMEM
+    bx = slab_depth((BOX, BOX, BOX), 4)
+    assert bx == 8
+    assert vmem_bytes((BOX, BOX, BOX), 4, bx) <= VMEM_BYTES
+
+
+@pytest.mark.parametrize("variant", ["overlap", "blocking"])
+def test_halo_interior_is_the_kernel(halo_512, variant):
+    """Each of the 4 steps computes its interior in one stencil7 kernel
+    call, in the halo.interior scope, and no XLA op of that scope is
+    left to pass over the field."""
+    text = halo_512(variant).as_text()
+    calls = _kernel_calls(text)
+    assert len(calls) == 4, calls
+    for name, op_name in calls:
+        assert "halo.interior" in op_name.split("/"), (name, op_name)
+        assert "halo_stencil7" in op_name, (name, op_name)
+    scopes = hlo.op_scopes(text)
+    others = [(m.group(1), m.group(2)) for line in hlo.logical_lines(text)
+              for m in [_ANY_OP.match(line)]
+              if m and m.group(2) not in _VIEWS | {"custom-call"}
+              and "halo.interior" in scopes.get(m.group(1), "").split("/")]
+    assert not others, others
+    _kernel_fits_vmem()
+    assert _fits(halo_512(variant))
+
+
+def test_profiled_interior_segment_is_the_kernel(halo_mesh, halo_field):
+    """HaloProgram(explicit=True)'s interior segment, the profiled
+    cell's post-comm region, is one stencil7 kernel call."""
+    compiled = HaloProgram(halo_mesh, explicit=True).interior.lower(
+        halo_field).compile()
+    calls = _kernel_calls(compiled.as_text())
+    assert len(calls) == 1 and "halo.interior" in calls[0][1], calls
+    _kernel_fits_vmem()
+    assert _fits(compiled)
 
 
 @pytest.mark.parametrize("variant", ["overlap", "blocking"])
 def test_halo_ops_carry_their_scope(halo_512, variant):
-    """Every fusion and collective-permute the chip's compiler emits for
-    the halo step names one of the program's halo.* scopes in its
-    op_name, which the profiler trace carries to each device op."""
+    """Every fusion, kernel call and collective-permute the chip's
+    compiler emits for the halo step names one of the program's halo.*
+    scopes in its op_name, which the profiler trace carries to each
+    device op."""
     text = halo_512(variant).as_text()
     scopes = hlo.op_scopes(text)
     ops = [m.groups() for m in map(_OPCODE.match, hlo.logical_lines(text))
            if m]
-    assert {"fusion", "collective-permute-start"} <= {op for _, op in ops}
+    assert ({"fusion", "custom-call", "collective-permute-start"}
+            <= {op for _, op in ops})
     for name, op in ops:
         parts = scopes.get(name, "").split("/")
         scope = [p for p in parts if p.startswith("halo.")]
