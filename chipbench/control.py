@@ -10,8 +10,10 @@ program's own ``sound`` readings) the numbers that ``correct`` compares
 or reads beside them, against the plain reference, and ``caught``:
 whether the pass rule of ``correct`` fails the variant on one of the
 configuration's limits. Exits 1 if the sound program is caught or a
-control or fault is not, on some seed. The benchmark's own runs never
-run this; its readings set the upper end of each limit (PERF.md).
+control or fault is not, on some seed. A configuration with no limits
+yet catches nothing, so the run exits 1 and its readings are what the
+limits are then set from (PERF.md). The benchmark's own runs never run
+this.
 """
 from __future__ import annotations
 
@@ -19,6 +21,7 @@ import argparse
 import json
 import os
 import sys
+import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 for p in (ROOT, os.path.join(ROOT, "src")):
@@ -40,17 +43,18 @@ def main(argv=None) -> int:
     devices = bench.device_check(cell.chips)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     enable_compile_cache()
-    limits = cell.config["limits"]
+    limits = cell.config.get("limits", {})
     wrong = 0
     for seed in args.seeds:
         ctx = bench.Context(cell.name, cell.config, cell.traffic, seed,
                             devices, peaks(devices[0].device_kind))
-        line = {"seed": seed}
+        line, t0 = {"seed": seed}, time.perf_counter()
         for variant, got in cell.driver.control_readings(ctx).items():
             caught = not all(bench.within_limit(v, limits[k])
                              for k, v in got.items() if k in limits)
             wrong += caught if variant == "sound" else not caught
             line[variant] = dict(got, caught=caught)
+        line["seconds"] = time.perf_counter() - t0
         print(json.dumps(line), flush=True)
     return 1 if wrong else 0
 

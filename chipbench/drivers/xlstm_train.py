@@ -8,24 +8,23 @@ the batch is built on the host, the step runs, and its loss is read.
 
 The weights are the benchmark's, made on the device in one call from
 the seed (``make_params``), so the reference can take the same ones
-without taking anything the program made. ``check`` frees the
-program's state and runs the float32 reference (``refs/xlstm.py``) over
-the same first batches, then reads
+without taking anything the program made. Set-up keeps two tensors of
+the program's on the host: the first clipped gradient as AdamW got it,
+read back from its first moment, and the change of the parameters over
+the checked steps. ``check`` frees the program's state, runs the float32
+reference (``refs/xlstm.py``) over the same first batches, and reads,
+a leaf being one layer's slice of a parameter,
 
     loss_gap           max over the checked steps of |loss - ref| / |ref|
-    grad_gap           median over leaves of | |g| - |g_ref| | / max(|g_ref|,
-                       median leaf |g_ref|), g being the first clipped
-                       gradient as AdamW got it, read back from its first
-                       moment
-    grad_gap_worst     the same, worst leaf
-    change_gap         worst leaf of the same for the change of every leaf
-                       over the checked steps, leaving out leaves whose
-                       reference gradient is under a thousandth of the
-                       median leaf's
-    change_gap_median  the same, median leaf
+    grad_err           median over leaves of |g - g_ref| / |g_ref|
+    grad_err_embed     the same for the embedding, and ``_head`` for the
+    grad_err_head      output head: the leaves half a batch moves most
+    change_err         median over leaves of the same for the change,
+    change_err_embed   leaving out leaves whose reference gradient is
+    change_err_head    under a thousandth of the median leaf's
 
-where a leaf is one layer's slice of a parameter; the numbers that the
-configuration gives a limit are compared (PERF.md says why these).
+The configuration gives a limit to each number in ``COMPARED``; the
+others are printed beside them (PERF.md says why these).
 
 Traffic keys: batch, seq_len, n_successors, checked_steps,
 reference_rows_per_block, trace_calls, optimizer (AdamW settings).
@@ -55,6 +54,9 @@ from repro.sharding import rules as R
 from repro.train.step import make_train_step
 
 EXCLUDE_BELOW = 1e-3          # of the median leaf's reference gradient
+# the numbers that decide ``correct``; the configuration limits each
+COMPARED = ("loss_gap", "grad_err", "grad_err_head", "change_err",
+            "change_err_head")
 
 
 def model_config(config: dict):
@@ -118,6 +120,18 @@ def _diff(a, b):
                         - y.astype(jnp.float32), a, b)
 
 
+@jax.jit
+def _scaled(tree, factor):
+    return jax.tree.map(lambda x: x * factor, tree)
+
+
+def to_host(tree):
+    """The tree's leaves as numpy arrays, all copies started at once."""
+    for x in jax.tree.leaves(tree):
+        x.copy_to_host_async()
+    return jax.device_get(tree)
+
+
 def flat_norms(tree) -> dict:
     """{leaf name: norm} from ``leaf_norms`` output, on the host."""
     out = {}
@@ -129,11 +143,15 @@ def flat_norms(tree) -> dict:
     return out
 
 
-def leaf_gaps(prog: dict, refn: dict, keep=None) -> dict:
-    """{leaf: | |prog| - |ref| | / max(|ref|, median leaf |ref|)}."""
-    names = [k for k in refn if keep is None or k in keep]
-    median = float(np.median([refn[k] for k in names]))
-    return {k: abs(prog[k] - refn[k]) / max(refn[k], median) for k in names}
+def leaf_errors(prog, ref) -> tuple:
+    """({leaf: |prog - ref| / |ref|}, {leaf: |ref|}) of two trees, the
+    first on the host or the device."""
+    prog, ref = jax.device_put((prog, ref))
+    refn = flat_norms(leaf_norms(ref))
+    dist = flat_norms(leaf_norms(_diff(prog, ref)))
+    err = {k: dist[k] / refn[k] if refn[k] > 0 else
+           (0.0 if dist[k] == 0 else math.inf) for k in refn}
+    return err, refn
 
 
 def worst(d: dict, n: int = 3) -> list:
@@ -141,48 +159,47 @@ def worst(d: dict, n: int = 3) -> list:
 
 
 def gaps(prog: dict, refr: dict) -> list:
-    """The numbers read from the readings of a run and of the reference,
-    each a dict with losses, grad_norms, change_norms, as (name, value)."""
+    """The numbers read from a run's readings against the reference's,
+    each a dict with ``losses`` and the trees ``grad`` and ``change``, as
+    (name, value)."""
     loss = max(abs(a - b) / abs(b)
                for a, b in zip(prog["losses"], refr["losses"]))
-    g = refr["grad_norms"]
-    floor = EXCLUDE_BELOW * float(np.median(list(g.values())))
-    moved = {k for k, v in g.items() if v >= floor}
-    grad = leaf_gaps(prog["grad_norms"], g)
-    change = leaf_gaps(prog["change_norms"], refr["change_norms"], moved)
-    print(f"worst grad leaves {worst(grad)}; worst change leaves "
-          f"{worst(change)}; {len(g) - len(moved)} of {len(g)} leaves "
-          f"left out of change_gap", file=sys.stderr)
+    g_err, g_ref = leaf_errors(prog["grad"], refr["grad"])
+    c_err, _ = leaf_errors(prog["change"], refr["change"])
+    floor = EXCLUDE_BELOW * float(np.median(list(g_ref.values())))
+    c_moved = {k: v for k, v in c_err.items() if g_ref[k] >= floor}
+    print(f"worst grad_err leaves {worst(g_err)}; worst change_err leaves "
+          f"{worst(c_moved)}; {len(c_err) - len(c_moved)} of {len(c_err)} "
+          f"leaves left out of the change", file=sys.stderr)
+    median = lambda d: float(np.median(list(d.values())))
     return [("loss_gap", loss),
-            ("grad_gap", float(np.median(list(grad.values())))),
-            ("grad_gap_worst", max(grad.values())),
-            ("change_gap", max(change.values())),
-            ("change_gap_median", float(np.median(list(change.values()))))]
+            ("grad_err", median(g_err)),
+            ("grad_err_embed", g_err["embed"]),
+            ("grad_err_head", g_err["lm_head"]),
+            ("change_err", median(c_moved)),
+            ("change_err_embed", c_err["embed"]),
+            ("change_err_head", c_err["lm_head"])]
 
 
 def reference_readings(seed: int, cfg_json: dict, tr: dict, data,
                        shapes, shardings, mode: str = "f32",
-                       rows_kept: int = None, altered: bool = False) -> dict:
+                       rows_kept: int = None) -> dict:
     """Readings of the reference over the checked steps' batches, from
-    the seeded weights. ``mode="fp8"`` is the control; ``rows_kept``
-    (the loss is the mean over only the first rows of every batch) and
-    ``altered`` (one element of the final output head is moved by 1.0)
-    plant faults in the reference put in the program's place."""
+    the seeded weights. ``mode="fp8"`` is the control; ``rows_kept`` (the
+    loss is the mean over only the first rows of every batch) plants a
+    fault in the reference put in the program's place."""
     batches = []
     for s in range(tr["checked_steps"]):
         tokens, labels = data.batch_at(s)
         batches.append((jnp.asarray(tokens[:rows_kept]),
                         jnp.asarray(labels[:rows_kept])))
-    p0 = make_params(seed, shapes, shardings)
     with jax.default_matmul_precision("highest"):
-        out = ref.train(p0, batches, cfg_json, tr["optimizer"], mode,
+        out = ref.train(make_params(seed, shapes, shardings), batches,
+                        cfg_json, tr["optimizer"], mode,
                         tr["reference_rows_per_block"])
-    final = out["params"]
-    if altered:
-        final = dict(final, lm_head=final["lm_head"].at[0, 0].add(1.0))
-    return {"losses": out["losses"],
-            "grad_norms": flat_norms(leaf_norms(out["first_grad"])),
-            "change_norms": flat_norms(leaf_norms(_diff(final, p0)))}
+    return {"losses": out["losses"], "grad": out["first_grad"],
+            "change": _diff(out["params"],
+                            make_params(seed, shapes, shardings))}
 
 
 class TrainSession:
@@ -239,47 +256,67 @@ class TrainSession:
         for i in range(steps):
             self.call()
             if i == 0:
-                m = leaf_norms(self.state["m"])
-                grads = {k: v / (1 - self.opt["b1"])
-                         for k, v in flat_norms(m).items()}
+                grad = to_host(_scaled(self.state["m"],
+                                       1.0 / (1 - self.opt["b1"])))
         p0 = make_params(self.seed, self.shapes, self.pshard)
-        change = flat_norms(leaf_norms(_diff(self.params, p0)))
+        change = to_host(_diff(self.params, p0))
         del p0
-        return {"losses": list(self.losses[:steps]), "grad_norms": grads,
-                "change_norms": change}
+        return {"losses": list(self.losses[:steps]), "grad": grad,
+                "change": change}
 
     def free_program(self):
         self.params = self.state = self.step_fn = None
         self._stack.close()
 
     def check(self):
+        limits = self.cfg_json.get("limits", {})
+        if set(limits) != set(COMPARED):
+            raise ValueError(f"the configuration's limits must be exactly "
+                             f"{COMPARED}; it has {limits}")
         self.free_program()
-        limits = self.cfg_json["limits"]
         refr = reference_readings(self.seed, self.cfg_json, self.tr,
                                   self.data, self.shapes, self.pshard)
         read = gaps(self.readings, refr)
+        self.readings = None
         print(f"readings {dict(read)}", file=sys.stderr)
         return [(name, value, limits[name]) for name, value in read
-                if name in limits]
+                if name in COMPARED]
 
 
 def control_readings(ctx) -> dict:
     """The program's own readings (``sound``: set-up and the checked
     steps of a run, no window), the control (the reference in fp8) and
     the faults planted in the reference put in the program's place, each
-    read against the float32 reference; a state left unchanged reads 1 on
-    change_gap by construction and needs no run."""
+    read against the float32 reference. Half a batch is a run of its own;
+    the other faults are made from the reference's own readings: a state
+    left unchanged (no gradient in the first moment, no change), one
+    element of the output head moved by 1.0 (``altered``), and the update
+    applied with its sign reversed."""
     session = TrainSession(ctx)
     session.free_program()
     args = (ctx.seed, session.cfg_json, session.tr, session.data,
             session.shapes, session.pshard)
     base = reference_readings(*args)
-    variants = {"control_fp8": {"mode": "fp8"},
-                "fault_half_batch": {"rows_kept": session.tr["batch"] // 2},
-                "fault_altered": {"altered": True}}
+    base = dict(base, grad=to_host(base["grad"]),
+                change=to_host(base["change"]))
+    change = base["change"]
+    head = change["lm_head"].copy()
+    head[0, 0] += 1.0
+    zeros = lambda t: jax.tree.map(np.zeros_like, t)
+    variants = {     # each made and read in turn, so one is on the device
+        "control_fp8": lambda: reference_readings(*args, mode="fp8"),
+        "fault_half_batch": lambda: reference_readings(
+            *args, rows_kept=session.tr["batch"] // 2),
+        "fault_unchanged": lambda: dict(base, grad=zeros(base["grad"]),
+                                        change=zeros(change)),
+        "fault_altered": lambda: dict(base, change=dict(change,
+                                                        lm_head=head)),
+        "fault_sign": lambda: dict(base, change=jax.tree.map(np.negative,
+                                                             change)),
+    }
     out = {"sound": dict(gaps(session.readings, base))}
-    for name, kw in variants.items():
-        out[name] = dict(gaps(reference_readings(*args, **kw), base))
+    for name, make in variants.items():
+        out[name] = dict(gaps(make(), base))
     return out
 
 

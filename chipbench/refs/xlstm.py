@@ -140,19 +140,21 @@ BLOCKS = {"mlstm": mlstm_block, "slstm": slstm_block}
 
 def loss_sum(params, tokens, labels, cfg: dict, mode: str = "f32"):
     """Summed token cross entropy plus z-loss terms over a block of rows
-    (divide by the number of tokens for the mean)."""
+    (divide by the number of tokens for the mean). The layer groups are a
+    scan over the stacked parameters, each group recomputed in the
+    backward pass."""
     E, V = cfg["d_model"], cfg["vocab_size"]
     x = params["embed"][tokens] * math.sqrt(E)
-    groups = cfg["n_layers"] // len(cfg["pattern"])
-    for g in range(groups):
-        for i, kind in enumerate(cfg["pattern"]):
-            p = jax.tree.map(lambda a: a[g], params[f"pos{i}"])
+    pattern = cfg["pattern"]
 
-            def block(x, p, kind=kind):
-                h = rms_norm(x, p["norm_mixer"], cfg["norm_eps"])
-                return x + BLOCKS[kind](p["mixer"], h, cfg, mode)
+    def group(x, ps):
+        for kind, p in zip(pattern, ps):
+            h = rms_norm(x, p["norm_mixer"], cfg["norm_eps"])
+            x = x + BLOCKS[kind](p["mixer"], h, cfg, mode)
+        return x, None
 
-            x = jax.checkpoint(block)(x, p)
+    stacked = [params[f"pos{i}"] for i in range(len(pattern))]
+    x, _ = jax.lax.scan(jax.checkpoint(group), x, stacked)
     x = rms_norm(x, params["final_norm"], cfg["norm_eps"])
     logits = einsum("bte,ev->btv", x, params["lm_head"], mode)
     logits = jnp.where(jnp.arange(logits.shape[-1]) < V, logits, -1e30)
@@ -161,12 +163,21 @@ def loss_sum(params, tokens, labels, cfg: dict, mode: str = "f32"):
     return jnp.sum(lse - picked) + Z_WEIGHT * jnp.sum(lse * lse)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg_items", "mode", "n"))
-def _block_grad(params, tokens, labels, cfg_items, mode, n):
+@functools.partial(jax.jit, static_argnames=("cfg_items", "mode", "rows"))
+def _loss_and_grads(params, tokens, labels, cfg_items, mode, rows):
     cfg = dict(cfg_items)
     cfg["pattern"] = list(cfg["pattern"])
-    f = lambda p: loss_sum(p, tokens, labels, cfg, mode) / n
-    return jax.value_and_grad(f)(params)
+    n = tokens.shape[0] * tokens.shape[1]
+    blocks = lambda a: a.reshape((-1, rows) + a.shape[1:])
+
+    def block(acc, rows_tl):
+        f = lambda p: loss_sum(p, *rows_tl, cfg, mode) / n
+        l, g = jax.value_and_grad(f)(params)
+        return (acc[0] + l, jax.tree.map(jnp.add, acc[1], g)), None
+
+    zero = (jnp.float32(0), jax.tree.map(jnp.zeros_like, params))
+    out, _ = jax.lax.scan(block, zero, (blocks(tokens), blocks(labels)))
+    return out
 
 
 def _freeze(cfg: dict) -> tuple:
@@ -178,17 +189,14 @@ def _freeze(cfg: dict) -> tuple:
 
 def loss_and_grads(params, tokens, labels, cfg: dict, mode: str = "f32",
                    rows_per_block: int = 4):
-    """Mean loss and its gradients over all rows, computed a block of
-    rows at a time so that the activations of one block fit."""
-    n = tokens.shape[0] * tokens.shape[1]
-    loss, grads = 0.0, None
-    for r in range(0, tokens.shape[0], rows_per_block):
-        l, g = _block_grad(params, tokens[r:r + rows_per_block],
-                           labels[r:r + rows_per_block], _freeze(cfg), mode,
-                           n)
-        loss = loss + l
-        grads = g if grads is None else jax.tree.map(jnp.add, grads, g)
-    return loss, grads
+    """Mean loss and its gradients over all rows, in one call that takes
+    a block of rows at a time, so that the activations of one block
+    fit."""
+    rows = min(rows_per_block, tokens.shape[0])
+    if tokens.shape[0] % rows:
+        raise ValueError(f"{tokens.shape[0]} rows do not divide into "
+                         f"blocks of {rows}")
+    return _loss_and_grads(params, tokens, labels, _freeze(cfg), mode, rows)
 
 
 def _decays(path) -> bool:
@@ -209,7 +217,8 @@ def learning_rate(opt: dict, step: int) -> float:
 
 
 @functools.partial(jax.jit, static_argnames=("b1", "b2", "eps", "wd",
-                                             "clip"))
+                                             "clip"),
+                   donate_argnums=(0, 1, 2, 3))
 def _adamw(params, grads, m, v, lr, b1c, b2c, b1, b2, eps, wd, clip):
     gnorm = jnp.sqrt(sum(jnp.sum(g * g) for g in jax.tree.leaves(grads)))
     scale = jnp.where(gnorm > clip, clip / jnp.maximum(gnorm, 1e-12), 1.0)
@@ -249,9 +258,10 @@ def train(params, batches, cfg: dict, opt: dict, mode: str = "f32",
     for s, (tokens, labels) in enumerate(batches, start=1):
         loss, grads = loss_and_grads(params, tokens, labels, cfg, mode,
                                      rows_per_block)
-        losses.append(float(loss))
+        losses.append(loss)
         params, m, v, clipped = adamw_step(params, grads, m, v, s, opt)
         if first_grad is None:
             first_grad = clipped
-        del grads
-    return {"losses": losses, "first_grad": first_grad, "params": params}
+        del grads, clipped
+    return {"losses": [float(l) for l in losses], "first_grad": first_grad,
+            "params": params}

@@ -13,7 +13,7 @@ def main():
     import repro.comm.halo as halo_mod
     out = {}
     for name in ("halo512-profiled-1chip", "halo512-fused-1chip",
-                 "halo512-fused-2x2"):
+                 "halo512-fused-2x2", "halo512-profiled-2x2"):
         cell = small_cell(name)
         real = cell.driver.build_program
         faults = {

@@ -6,28 +6,35 @@ import jax
 from chipbench import bench
 
 
-# Limits of the training comparison at this size (CPU readings, seeds 12
-# and 13): the sound program reads loss 3.1e-4, grad (median leaf)
-# 5.6e-4-1.2e-3, change 1.4e-3-2.1e-3; the fp8 control 2.8e-3-4.3e-3,
-# 8.7e-3-1.0e-2, 7.5e-3-8.5e-3. Bfloat16 rounding weighs otherwise in a
-# model of width 64 and 2 blocks than at the cell's sizes, whose limits
-# are in chipbench/configs/xlstm10-125m.json. Both block kinds are in the
-# small model, so the sLSTM of the reference is compared with the
-# program's too.
-SMOKE_LIMITS = {"loss_gap": 1e-3, "grad_gap": 4e-3, "change_gap": 0.04}
+# Limits of the training comparison at this size, each between the sound
+# program's largest reading and the control's or a fault's smallest. CPU
+# readings over 2 checked steps, seeds 12 to 14, sound / fp8 control:
+# loss_gap 9.0e-5-3.1e-4 / 2.8e-3-4.3e-3; grad_err 0.025-0.052 /
+# 0.218-0.229; grad_err_head 0.014-0.017 / 0.139-0.147; change_err
+# 0.095-0.129 / 0.398-0.438; change_err_head 0.061-0.071 / 0.293-0.308.
+# Half a batch reads 0.69-0.91 on every _err, a state left unchanged 1,
+# the update's sign reversed 2 on change_err, the altered head 1122-1138
+# on change_err_head. The cell's own limits go into
+# chipbench/configs/xlstm10-125m.json once readings on the chip set them.
+# Both block kinds are in the small model, so the sLSTM of the reference
+# is compared with the program's too.
+SMOKE_LIMITS = {"loss_gap": 1e-3, "grad_err": 0.12, "grad_err_head": 0.05,
+                "change_err": 0.25, "change_err_head": 0.15}
 
 
-# The training cell's files (configuration, traffic, driver, reference)
-# stay while the cell is out of BENCHMARK.json, until a control separates
-# from the sound program at its size (PERF.md); the tests still run it.
-TRAIN_CELL = {"name": "xlstm10-train-b16s2048", "config": "xlstm10-125m",
-              "traffic": "xlstm10-train-b16s2048", "chips": 1}
+# Cells whose files are in the tree and which BENCHMARK.json does not list
+# yet, for want of chip readings (PERF.md section 7); the tests run them.
+DEFERRED = [
+    {"name": "xlstm10-train-b16s2048", "config": "xlstm10-125m",
+     "traffic": "xlstm10-train-b16s2048", "chips": 1},
+    {"name": "halo512-profiled-2x2", "config": "comb-halo-512",
+     "traffic": "halo512-profiled-2x2", "chips": 4},
+]
 
 
 def small_cell(name: str):
     b = bench.load_benchmark()
-    if name == TRAIN_CELL["name"]:
-        b["workloads"].append(TRAIN_CELL)
+    b["workloads"] += [w for w in DEFERRED if w["name"] == name]
     cell = bench.resolve(name, b)
     if cell.config["driver"] == "halo":
         cell.config = dict(cell.config, box=16)

@@ -2,6 +2,8 @@
 put in the program's place) fails the cell's limits, at sizes a CPU run
 holds; on the chip ``chipbench/control.py`` reads it at the cells' own
 sizes."""
+import pytest
+
 from chipbench.tests.helpers import context, small_cell
 
 
@@ -12,11 +14,26 @@ def test_halo_control_bf16_fails_the_limit():
     assert got["control_bf16"]["halo_max_err_rel"] > limit
 
 
-def test_train_control_and_faults_fail_a_limit():
+@pytest.fixture(scope="module")
+def train_readings():
     cell = small_cell("xlstm10-train-b16s2048")
-    got = cell.driver.control_readings(context(cell, 12))
-    limits = cell.config["limits"]
-    assert all(got["sound"][k] <= lim for k, lim in limits.items()), got
-    for variant in ("control_fp8", "fault_half_batch", "fault_altered"):
-        assert any(got[variant][k] > lim for k, lim in limits.items()), \
-            (variant, got[variant])
+    return cell.config["limits"], cell.driver.control_readings(
+        context(cell, 12))
+
+
+# variant -> the limit it fails (None: it passes every limit)
+TRAIN_VARIANTS = {"sound": None, "control_fp8": "grad_err_head",
+                  "fault_half_batch": "grad_err_head",
+                  "fault_unchanged": "change_err",
+                  "fault_altered": "change_err_head",
+                  "fault_sign": "change_err"}
+
+
+@pytest.mark.parametrize("variant", sorted(TRAIN_VARIANTS))
+def test_train_control_and_faults_fail_a_named_limit(variant,
+                                                     train_readings):
+    limits, got = train_readings
+    failed = {k for k, lim in limits.items() if not got[variant][k] <= lim}
+    named = TRAIN_VARIANTS[variant]
+    assert (named in failed) if named else not failed, (variant,
+                                                        got[variant])

@@ -27,7 +27,8 @@ def test_halo_cells_catch_each_fault():
     got = json.loads(proc.stdout.strip().splitlines()[-1])
     for case, correct in got.items():
         assert correct is case.endswith("/sound"), (case, got)
-    assert "halo512-fused-2x2/no_exchange" in got
+    assert {"halo512-fused-2x2/no_exchange",
+            "halo512-profiled-2x2/no_exchange"} <= set(got)
 
 
 def _broken_step(kind):
@@ -44,18 +45,37 @@ def _broken_step(kind):
             new_p, new_s, metrics = real(params, state, batch)
             if kind == "unchanged":
                 return params, state, metrics
+            if kind == "sign":                    # the update reversed
+                return jax.tree.map(lambda p, q: 2 * p - q, params,
+                                    new_p), new_s, metrics
             lm = new_p["lm_head"].at[0, 0].add(1.0)            # altered
             return dict(new_p, lm_head=lm), new_s, metrics
         return step
     return make
 
 
-@pytest.mark.parametrize("fault", [None, "unchanged", "half_batch",
-                                   "altered"])
+# fault -> a limit it fails (None: the sound program, which fails none)
+TRAIN_FAULTS = {None: None, "unchanged": "change_err",
+                "half_batch": "grad_err_head", "altered": "change_err_head",
+                "sign": "change_err"}
+
+
+@pytest.mark.parametrize("fault", list(TRAIN_FAULTS))
 def test_train_cell_catches_each_fault(fault, monkeypatch):
     cell = small_cell("xlstm10-train-b16s2048")
     if fault:
         monkeypatch.setattr(cell.driver, "make_train_step",
                             _broken_step(fault))
     result = run(cell)
+    failed = {k for k, c in result["checks"].items()
+              if not c["value"] <= c["limit"]}
     assert result["correct"] is (fault is None), result["checks"]
+    if fault:
+        assert TRAIN_FAULTS[fault] in failed, result["checks"]
+
+
+def test_train_cell_refuses_a_config_without_its_limits():
+    cell = small_cell("xlstm10-train-b16s2048")
+    cell.config = dict(cell.config, limits={"grad_err": 0.12})
+    with pytest.raises(ValueError, match="limits"):
+        run(cell)
